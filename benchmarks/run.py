@@ -146,6 +146,8 @@ def main() -> None:
     ap.add_argument("--fast", action="store_true",
                     help="CI smoke mode: shrunken sweeps, same code paths")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = args.only.split(",") if args.only else list(ALL)
     unknown = [n for n in names if n not in ALL]
     if unknown:

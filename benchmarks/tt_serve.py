@@ -98,23 +98,6 @@ def run(fast: bool = False, arch: str = "gemma3-1b", eps: float = 0.2,
     return result
 
 
-def _teacher_logits(model, params, prompts):
-    """Teacher-forced per-position logits: drive ``decode_step`` across the
-    prompt and stack every position's next-token logits -> (b, S-1, V).
-    This is the measured surface for the quantized agreement gate —
-    ``generate``'s ``prompt_logits`` is last-position only, which would
-    reduce the gate to a handful of samples."""
-    import jax.numpy as jnp
-    b, s = prompts.shape
-    cache = model.init_cache(b, s)
-    outs = []
-    for t in range(s - 1):
-        logits, cache = model.decode_step(
-            params, cache, jnp.asarray(prompts[:, t:t + 1]))
-        outs.append(np.asarray(logits, np.float32).reshape(b, -1))
-    return np.stack(outs, 1)
-
-
 def run_quant(fast: bool = False, arch: str = "gemma3-1b", eps: float = 0.2,
               write_json: bool = True, min_agreement: float = 0.99,
               agree_tol: float = 0.05,
@@ -127,7 +110,7 @@ def run_quant(fast: bool = False, arch: str = "gemma3-1b", eps: float = 0.2,
 
       * teacher-forced next-token agreement vs the dense oracle ≥ 99%,
         measured over every prompt position (b×(S-1) positions via
-        ``_teacher_logits``, so the bound is measured, not vacuous).
+        ``teacher_forced_logits``, so the bound is measured, not vacuous).
         Agreement is TIE-TOLERANT: a position agrees when the quantized
         model's argmax matches the oracle token, or scores it within
         ``agree_tol``·(logit scale) of its own argmax.  On synthetic
@@ -178,25 +161,18 @@ def run_quant(fast: bool = False, arch: str = "gemma3-1b", eps: float = 0.2,
     for mode, p in (("dense", params_rx), ("tt", params_tt),
                     ("tt-int8", params_q)):
         dt, _ = _decode(model, p, prompts, gen, max_len)
-        tf[mode] = _teacher_logits(model, p, prompts)
+        tf[mode] = model_common.teacher_forced_logits(model, p, prompts)
         rows.append((mode, b * (gen - 1) / max(dt, 1e-9), tt_param_bytes(p)))
 
     lrx, lq = tf["dense"], tf["tt-int8"]
     positions = lrx.shape[0] * lrx.shape[1]
-    scale = float(np.max(np.abs(lrx)))
-    oracle = np.argmax(lrx, -1)                       # (b, S-1)
-    raw_agree = float(np.mean(np.argmax(lq, -1) == oracle))
-    # tie-tolerant: the quantized model must score the oracle token within
-    # agree_tol*scale of its own argmax (0 deficit == raw argmax match)
-    deficit = np.max(lq, -1) - np.take_along_axis(
-        lq, oracle[..., None], -1)[..., 0]
-    agree = float(np.mean(deficit <= agree_tol * scale))
+    raw_agree = float(np.mean(np.argmax(lq, -1) == np.argmax(lrx, -1)))
+    agree = model_common.tie_tolerant_agreement(lq, lrx, tol=agree_tol)
     # the wide cores are held to a 5x tighter bound — only quantization is
     # allowed to move logits materially (exact argmax would flip on
     # rounding noise at the near-tied positions)
-    tt_deficit = np.max(tf["tt"], -1) - np.take_along_axis(
-        tf["tt"], oracle[..., None], -1)[..., 0]
-    tt_agree = float(np.mean(tt_deficit <= 0.2 * agree_tol * scale))
+    tt_agree = model_common.tie_tolerant_agreement(
+        tf["tt"], lrx, tol=0.2 * agree_tol)
     wide_leaf, dense_leaf = tt_leaf_bytes(params_tt)
     q_leaf, _ = tt_leaf_bytes(params_q)
 

@@ -69,10 +69,7 @@ def main() -> None:
           f"params={n_params/1e6:.1f}M pods={args.pods}")
 
     mesh = make_host_mesh()
-    if hasattr(jax, "set_mesh"):          # newer jax: ambient mesh API
-        jax.set_mesh(mesh)
-    else:
-        mesh.__enter__()                  # 0.4.x: context-manager mesh
+    jax.set_mesh(mesh)
     shape = ShapeConfig("fedttd", args.seq, args.batch, "train")
     optimizer = AdamW(learning_rate=cosine_schedule(3e-4, 10, args.steps))
     step_fn = jax.jit(

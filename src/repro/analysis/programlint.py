@@ -184,8 +184,8 @@ def _trace_tt_contract(shape: "TTShape") -> EntryReport:
     x2 = jnp.zeros((shape.b, shape.n_in()), jnp.float32)
     cores = [jnp.zeros(s, _np_dtype(dt))
              for s, dt in zip(shape.cores, shape.dtypes)]
-    scales = ([jnp.float32(1.0) if dt == "int8" else None
-               for dt in shape.dtypes]
+    scales = ([jnp.ones((s[0],), jnp.float32) if dt == "int8" else None
+               for s, dt in zip(shape.cores, shape.dtypes)]
               if any(dt == "int8" for dt in shape.dtypes) else None)
 
     def run(x2, *cores):
@@ -374,14 +374,25 @@ def _check_dtypes(rep: EntryReport) -> List[Finding]:
 _BANNED_PRIMS = {"infeed", "outfeed", "device_put"}
 
 
+def _is_host_eqn(eqn) -> bool:
+    """A primitive that reaches the host: a banned transfer, any callback,
+    or anything carrying a debug effect.  ``jax.debug.print`` traces to its
+    own ``debug_print`` primitive (no "callback" in the name), so the name
+    alone misses it; its effect does not."""
+    name = eqn.primitive.name
+    if name in _BANNED_PRIMS or "callback" in name:
+        return True
+    return any(type(e).__name__ in ("DebugEffect", "OrderedDebugEffect")
+               for e in eqn.effects)
+
+
 def _check_callbacks(rep: EntryReport) -> List[Finding]:
     findings = []
     flagged: Set[str] = set()
     for j in _iter_jaxprs(rep.jaxpr):
         for eqn in j.eqns:
             name = eqn.primitive.name
-            if (name in _BANNED_PRIMS or "callback" in name) \
-                    and name not in flagged:
+            if _is_host_eqn(eqn) and name not in flagged:
                 flagged.add(name)
                 findings.append(Finding(
                     "PRG002", f"entry:{rep.name}", 0,

@@ -144,8 +144,8 @@ def save_tt_payload(directory: str, payload, extra: Optional[Dict] = None,
     right serving-rule set (and refuse a payload from the wrong arch).
 
     quant: integer storage format (``"int8"``) or None.  When set, TT cores
-    are written symmetrically quantized (one scale per core, stored beside
-    it as ``<key>__core<k>__scale``) — the on-disk payload shrinks ~4x on
+    are written symmetrically quantized (one scale per leading-rank row of
+    each core, stored beside it as ``<key>__core<k>__scale``) — the on-disk payload shrinks ~4x on
     the cores.  ``load_tt_payload`` dequantizes back to the wide core dtype;
     the restored values sit exactly on the quantization grid, so a serving-
     side requantization (``tt_native_params(quant=...)`` with absmax
@@ -184,7 +184,7 @@ def save_tt_payload(directory: str, payload, extra: Optional[Dict] = None,
             if qdt is not None:
                 meta["tt"]["quant"] = {"dtype": quant, "calib": quant_calib}
                 for k, g in enumerate(c.tt.cores):
-                    q, s = _ttl.quantize_array(
+                    q, s = _ttl.quantize_core(
                         jax.numpy.asarray(g), dtype=qdt, calib=quant_calib
                     )
                     arrays[f"{key}__core{k}"] = np.asarray(jax.device_get(q))
@@ -242,6 +242,7 @@ def load_tt_payload(directory: str, like, verify: bool = True
     import jax.numpy as jnp
 
     from repro.core.compression import CompressedParam
+    from repro.core import tt_linear as _ttl
     from repro.core.tt import TTTensor
 
     if not os.path.exists(os.path.join(directory, "_COMMITTED")):
@@ -278,9 +279,8 @@ def load_tt_payload(directory: str, like, verify: bool = True
                     # dequantize to the wide core dtype: restored values sit
                     # exactly on the integer grid, so requantizing at serve
                     # time (absmax) is bit-identical to what was saved
-                    arr = (np.asarray(arr, np.float32)
-                           * np.asarray(data[f"{key}__core{k}__scale"],
-                                        np.float32))
+                    arr = np.asarray(_ttl.dequantize_core(
+                        arr, data[f"{key}__core{k}__scale"]))
                 cores.append(jnp.asarray(arr, jnp.dtype(cd)))
             tt = TTTensor(
                 cores=cores, shape=tuple(m["tt"]["shape"]),

@@ -25,7 +25,6 @@ Scheduling
 
 from __future__ import annotations
 
-import functools
 from dataclasses import dataclass, field
 from typing import Dict, List, Tuple
 
@@ -113,12 +112,7 @@ class BucketExecutor:
         )
         fn = _EXEC_CACHE.get(key)
         if fn is None:
-            try:
-                fn = _tt.ttd_static_batched.lower(
-                    stacked, **statics
-                ).compile()
-            except Exception:      # AOT unavailable: fall back to lazy jit
-                fn = functools.partial(_tt.ttd_static_batched, **statics)
+            fn = _tt.ttd_static_batched.lower(stacked, **statics).compile()
             _EXEC_CACHE[key] = fn
             self.stats.compiles += 1
         else:
@@ -129,11 +123,8 @@ class BucketExecutor:
     def _ndev(self) -> int:
         if self.mesh is None:
             return 1
-        try:
-            from repro.launch.mesh import data_axis_size
-            return data_axis_size(self.mesh, self.data_axis)
-        except Exception:
-            return 1
+        from repro.launch.mesh import data_axis_size  # lazy: launch imports core
+        return data_axis_size(self.mesh, self.data_axis)
 
     def _place(self, stacked: jax.Array) -> jax.Array:
         """Block-shard the batch axis over the data axis (no-op off-mesh)."""

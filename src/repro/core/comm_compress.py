@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.core import tt as _tt
 
 
@@ -109,8 +108,8 @@ def pod_sync_tt(
             acc = acc.reshape(-1, r) @ g.reshape(r, -1)
         return acc.reshape(delta.shape)
 
-    # newer jax: mark the accumulator axis-varying explicitly (no-op on old)
-    init = compat.pvary(jnp.zeros(delta.shape, jnp.float32), (axis_name,))
+    # the accumulator sums per-pod values: mark it varying over the axis
+    init = jax.lax.pvary(jnp.zeros(delta.shape, jnp.float32), (axis_name,))
     total = jax.lax.fori_loop(0, n_pods, lambda p, s: s + rec_one(p), init)
     avg = (total / n_pods).astype(delta.dtype)
     return avg, resid

@@ -128,11 +128,8 @@ def decompress_param(c: CompressedParam) -> jax.Array:
 
 
 def _default_mesh():
-    try:
-        from repro.launch.sharding import current_mesh
-        return current_mesh()
-    except Exception:                              # launch layer unavailable
-        return None
+    from repro.launch.sharding import current_mesh  # lazy: launch imports core
+    return current_mesh()
 
 
 class TTCompressor:

@@ -41,6 +41,10 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+# f32 products at DEFAULT precision run as one bf16 pass on TPU; the
+# reflectors compound over N steps, so every HBD product asks for full f32.
+_HI = jax.lax.Precision.HIGHEST
+
 
 class HouseResult(NamedTuple):
     q: jax.Array  # the pivot value: -sign(x1) * ||x||
@@ -107,10 +111,10 @@ def house_mm_update(
 
     if _is_left_static(order):
         vec1 = v * inv_beta                      # (M,)   — VEC DIVISION stage
-        vec2 = v @ sub                           # (N,)   — GEMM #1
+        vec2 = jnp.dot(v, sub, precision=_HI)    # (N,)   — GEMM #1
         upd = jnp.outer(vec1, vec2)              # (M, N) — GEMM #2 (rank-1)
     else:
-        vec1 = sub @ v                           # (M,)   — GEMM #1
+        vec1 = jnp.dot(sub, v, precision=_HI)    # (M,)   — GEMM #1
         vec2 = v * inv_beta                      # (N,)   — VEC DIVISION stage
         upd = jnp.outer(vec1, vec2)              # (M, N) — GEMM #2 (rank-1)
     return sub + upd
@@ -122,30 +126,17 @@ def _is_left_static(order) -> bool:
     raise TypeError("order must be a static python int (0=left, 1=right)")
 
 
-@functools.partial(jax.jit, static_argnames=("compute_uv",))
-def householder_bidiagonalize(
-    a: jax.Array, compute_uv: bool = True
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Paper Algorithm 2: A (M×N, M>=N) -> (U_B, B, V_B^T), A = U_B B V_B^T.
+@jax.jit
+def hbd_reduce(a: jax.Array) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Algorithm 2's reduction loop (i = 1..N) on a tall f32 A (M×N).
 
-    B is upper-bidiagonal (returned as a dense M×N matrix whose only nonzeros
-    are B[i,i] and B[i,i+1] — the dense form is what downstream phase-2
-    diagonalization consumes).
-
-    Implements both loops of Algorithm 2:
-      * reduction   (i = 1..N): HOUSE + HOUSE_MM_UPDATE on A, storing the
-        Householder vectors *in place* in A's zeroed-out wings — the
-        software analogue of the paper's "on-chip retention of Householder
-        vectors" (nothing is written back to a separate buffer).
-      * accumulation(i = N..1): HOUSE_MM_UPDATE on U_B and V_B^T using the
-        retained vectors.
+    Returns ``(a_red, diag, super_)``: the Householder vectors retained in
+    place in A's zeroed-out wings (column i below the diagonal holds v_L,
+    row i right of the superdiagonal holds v_R) — the software analogue of
+    the paper's "on-chip retention of Householder vectors" — and B's two
+    bands.  Reflector i is ``I + v v^T / (v[i] q)`` with q its band value.
     """
     m, n = a.shape
-    if m < n:
-        raise ValueError(f"HBD expects M >= N, got {a.shape}; transpose first")
-    orig_dtype = a.dtype
-    a = a.astype(jnp.float32)
-
     rows = jnp.arange(m)
     cols = jnp.arange(n)
 
@@ -184,12 +175,39 @@ def householder_bidiagonalize(
 
     diag0 = jnp.zeros((n,), jnp.float32)
     super0 = jnp.zeros((n,), jnp.float32)
-    a_red, diag, super_ = jax.lax.fori_loop(
-        0, n, reduction_step, (a, diag0, super0)
-    )
+    return jax.lax.fori_loop(0, n, reduction_step, (a, diag0, super0))
 
-    # Dense bidiagonal B (M×N): diag + superdiagonal.
-    b = jnp.zeros((m, n), jnp.float32)
+
+@functools.partial(jax.jit, static_argnames=("compute_uv",))
+def householder_bidiagonalize(
+    a: jax.Array, compute_uv: bool = True
+) -> Tuple[jax.Array, jax.Array, jax.Array]:
+    """Paper Algorithm 2: A (M×N, M>=N) -> (U_B, B, V_B^T) with thin U_B.
+
+    A = U_B · B · V_B^T, U_B^T U_B = I_N, V_B orthogonal (N×N).
+
+    B is upper-bidiagonal (returned as a dense N×N matrix whose only nonzeros
+    are B[i,i] and B[i,i+1] — the dense form is what downstream phase-2
+    diagonalization consumes; rows N..M of the full M×N form are zero).
+    U_B is the thin M×N accumulation: the first N columns of the full
+    product of left reflectors, which is all the SVD composes (an M×M U_B
+    is 4 TB at M = 2^20).
+
+    Implements both loops of Algorithm 2:
+      * reduction   (i = 1..N): ``hbd_reduce``.
+      * accumulation(i = N..1): HOUSE_MM_UPDATE on U_B and V_B^T using the
+        retained vectors.
+    """
+    m, n = a.shape
+    if m < n:
+        raise ValueError(f"HBD expects M >= N, got {a.shape}; transpose first")
+    orig_dtype = a.dtype
+    a_red, diag, super_ = hbd_reduce(a.astype(jnp.float32))
+
+    rows = jnp.arange(m)
+    cols = jnp.arange(n)
+    # Dense bidiagonal B (N×N): diag + superdiagonal.
+    b = jnp.zeros((n, n), jnp.float32)
     b = b.at[cols, cols].set(diag)
     b = b.at[cols[:-1], cols[:-1] + 1].set(super_[:-1])
 
@@ -198,7 +216,7 @@ def householder_bidiagonalize(
         return eye_small, b.astype(orig_dtype), eye_small
 
     # ---- accumulation loop (Alg. 2 lines 14-18), i = N..1 ----
-    u_b0 = jnp.eye(m, dtype=jnp.float32)
+    u_b0 = jnp.eye(m, n, dtype=jnp.float32)
     v_bt0 = jnp.eye(n, dtype=jnp.float32)
 
     def accumulation_step(k, carry):
@@ -209,9 +227,9 @@ def householder_bidiagonalize(
 
         v_l = jnp.where(row_mask, a_red[:, i], 0.0)
         q_l = diag[i]
-        # update ALL columns of U_B in the active row block (the paper's
-        # U_B[i:M, :] — using i+1: for columns loses the i-th column's mix).
-        ucols = jnp.arange(m) >= i
+        # update columns i.. of the thin U_B in the active row block:
+        # columns j < i are still e_j, on which H_i acts as the identity.
+        ucols = cols >= i
         usub = jnp.where(row_mask[:, None] & ucols[None, :], u_b, 0.0)
         usub = house_mm_update(q_l, v_l, usub, 0, row_mask, ucols)
         u_b = jnp.where(row_mask[:, None] & ucols[None, :], usub, u_b)

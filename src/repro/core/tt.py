@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.svd import svd as _svd_fn
+from repro.core.svd import svd as _svd_fn, svd_host_diag
 from repro.core import truncation as _trunc
 
 
@@ -95,9 +95,11 @@ def ttd(
     for k in range(d - 1):
         rows = ranks[-1] * shape[k]
         mat = _as_2d(w_temp, rows)                          # Reshape (line 7)
-        res = _svd_fn(
-            jnp.asarray(mat), method=svd_method, hbd_impl=hbd_impl
-        )                                                   # SVD+Sorting (8-9)
+        if svd_method == "two_phase":                       # SVD+Sorting (8-9)
+            res = svd_host_diag(jnp.asarray(mat), hbd_impl=hbd_impl)
+        else:
+            res = _svd_fn(jnp.asarray(mat), method=svd_method,
+                          hbd_impl=hbd_impl)
         u = np.asarray(res.u)
         s = np.asarray(res.s)
         vt = np.asarray(res.vt)
@@ -119,7 +121,8 @@ def tt_reconstruct(tt: TTTensor, dtype=None):
     acc = cores[0]                                  # (1, n_1, r_1)
     for g in cores[1:]:
         r = g.shape[0]
-        acc = _as_2d(acc, acc.size // r) @ _as_2d(g, r)     # contraction (2)
+        acc = jnp.dot(_as_2d(acc, acc.size // r), _as_2d(g, r),
+                      precision=jax.lax.Precision.HIGHEST)  # contraction (2)
     out = acc.reshape(tt.shape)
     return out.astype(dtype) if dtype is not None else out
 

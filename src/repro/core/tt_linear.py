@@ -15,17 +15,20 @@ Quantized storage (int8, int4-ready)
 ------------------------------------
 Decode is memory-bound, so the cores' *storage* bytes are the decode
 latency.  ``quantize_tt`` rounds every core to a symmetric integer grid —
-one scale per core (per-core absmax calibration), one scale per lead ROW
-(per layer, and per (layer, expert) for expert banks) — and the fused
-kernels dequantize *inside* the contraction: HBM streams int8, the MXU
-computes f32, and the scale multiply folds into the per-tile epilogue
-(``kernels/tt_contract`` q-variants).  The wide form of a stored core never
-exists outside a VMEM tile; the only wide intermediate is the per-layer
-lead-absorbed first core, which is transient activation-sized traffic (and
-``r_s``× smaller than the core it absorbs).  With round-to-nearest the
-absolute error per element is at most ``scale/2 = absmax/(2·qmax)`` —
-``≲ 0.2%`` of the core's dynamic range for int8 — which is an order of
-magnitude inside the TT truncation ε the payload already carries.
+one scale per leading-rank row of each core (absmax calibration), one scale
+per lead ROW (per layer, and per (layer, expert) for expert banks) — and
+the fused kernels dequantize *inside* the contraction: HBM streams int8,
+the MXU computes f32, and each core's row scales multiply the f32 partial
+product that meets that core (``kernels/tt_contract`` q-variants).  Row
+scales, not one scale per core: TT-SVD leaves a decaying singular spectrum
+along the rank rows, and a single absmax grid rounds the small rows away
+(tie-tolerant agreement fell to 84% at qwen1.5-0.5b's widths on a v5e).
+The wide form of a stored core never exists outside a VMEM tile; the only
+wide intermediate is the per-layer lead-absorbed first core, which is
+transient activation-sized traffic (and ``r_s``× smaller than the core it
+absorbs).  With round-to-nearest the absolute error per element is at most
+``scale/2 = absmax/(2·qmax)`` of its row — ``≲ 0.2%`` of the row's dynamic
+range for int8.
 
 Representation
 --------------
@@ -76,8 +79,9 @@ class TTLinear:
     dtype: Any = jnp.bfloat16        # activation dtype of the dense original
     experts: Optional[int] = None    # expert-bank size E (extra lead mode
                                      # kept as a batch axis at apply time)
-    scales: Optional[List[jax.Array]] = None   # per-core () f32 dequant
-                                     # scales (None = wide storage)
+    scales: Optional[List[jax.Array]] = None   # per-core (r,) f32 dequant
+                                     # scales, one per leading-rank row
+                                     # (None = wide storage)
     lead_scale: Optional[jax.Array] = None     # per-lead-row f32 scales:
                                      # (L,) stacked / (L, E) experts / ()
 
@@ -152,7 +156,7 @@ def select_layer(t: TTLinear, idx) -> TTLinear:
 
 
 # ---------------------------------------------------------------------------
-# Quantization: symmetric integer cores, per-core / per-lead-row scales
+# Quantization: symmetric integer cores, per-rank-row / per-lead-row scales
 # ---------------------------------------------------------------------------
 
 # storage formats the serving stack accepts; int4 rides the same machinery
@@ -215,22 +219,44 @@ def dequantize_array(q: jax.Array, scale: jax.Array, axis=None) -> jax.Array:
     return q.astype(jnp.float32) * s
 
 
+def _row_axes(g) -> Tuple[int, ...]:
+    """Axes a core's row scale reduces over: all but the leading rank."""
+    return tuple(range(1, g.ndim))
+
+
+def quantize_core(g: jax.Array, dtype=jnp.int8,
+                  calib: str = "absmax") -> Tuple[jax.Array, jax.Array]:
+    """(values, (r,) scales) of a core (r, ...): one scale per leading-rank
+    row.  The chain is linear in each core, so a row scale commutes onto
+    the partial product's matching column."""
+    return quantize_array(g, dtype=dtype, calib=calib, axis=_row_axes(g))
+
+
+def dequantize_core(q: jax.Array, scale: jax.Array) -> jax.Array:
+    """Inverse of ``quantize_core`` (f32 values; exact for the grid).  A 0-d
+    ``scale`` is one scale for the whole core: int8 checkpoints written
+    before row scales stored that form, and still load."""
+    scale = jnp.asarray(scale)
+    if scale.ndim == 0:
+        return dequantize_array(q, scale)
+    return dequantize_array(q, scale, axis=_row_axes(q))
+
+
 def quantize_tt(t: TTLinear, dtype=jnp.int8,
                 calib: str = "absmax") -> TTLinear:
     """Quantize a TTLinear's resident payload to symmetric integers.
 
-    Each core gets ONE scale (per-core absmax — cores are the shared
-    compressed payload, already balanced by the TT-SVD's norm split); the
-    lead table gets one scale PER ROW over its rank axis (per layer, and
-    per (layer, expert) for expert banks) because row magnitudes vary with
+    Each core gets one scale per leading-rank row (``quantize_core``); the
+    lead table one scale PER ROW over its rank axis (per layer, and per
+    (layer, expert) for expert banks) because row magnitudes vary with
     layer depth.  Max absolute error per element is scale/2 =
-    amax/(2·qmax): <= amax/254 for int8.  Apply-time dequantization stays
-    inside the fused kernels (``tt_apply`` hands int8 cores + scales down
-    to ``kernels/tt_contract``)."""
+    amax/(2·qmax) of its row: <= amax/254 for int8.  Apply-time
+    dequantization stays inside the fused kernels (``tt_apply`` hands int8
+    cores + scales down to ``kernels/tt_contract``)."""
     assert not t.quantized, "TTLinear is already quantized"
     cores, scales = [], []
     for g in t.cores:
-        q, s = quantize_array(g, dtype=dtype, calib=calib)
+        q, s = quantize_core(g, dtype=dtype, calib=calib)
         cores.append(q)
         scales.append(s)
     lead, lead_scale = t.lead, None
@@ -247,7 +273,7 @@ def quantize_tt(t: TTLinear, dtype=jnp.int8,
 def dequantize_tt(t: TTLinear) -> TTLinear:
     """Back to wide (f32) storage — the parity oracle for the fused path."""
     assert t.quantized, "TTLinear is not quantized"
-    cores = [dequantize_array(g, s) for g, s in zip(t.cores, t.scales)]
+    cores = [dequantize_core(g, s) for g, s in zip(t.cores, t.scales)]
     lead = t.lead
     if lead is not None:
         lead = dequantize_array(lead, t.lead_scale, axis=-1)
@@ -281,11 +307,15 @@ def tt_apply(x: jax.Array, t: TTLinear) -> jax.Array:
 
     g0 = t.cores[0]                                   # (r_s, n_1, r_1)
     lead = t.lead
-    if lead is not None and t.quantized:
-        # the lead row is tiny — dequantize it host-side; its scale and the
-        # first core's scale fold into the (transient) absorbed core, so
-        # the tail cores are the only wide-dequant work left for the kernel
-        lead = dequantize_array(lead, t.lead_scale)
+    chain_scales = None
+    if t.quantized:
+        # the lead row and the first core fold into the (transient) wide
+        # absorbed core, so the tail cores are the only dequant work left
+        # for the kernel
+        g0 = dequantize_core(g0, t.scales[0])
+        if lead is not None:
+            lead = dequantize_array(lead, t.lead_scale)
+        chain_scales = [None] + list(t.scales[1:])    # tail stays int8
     if lead is not None:
         g0 = jnp.einsum(
             "r,rns->ns", lead.astype(jnp.float32), g0.astype(jnp.float32)
@@ -293,10 +323,6 @@ def tt_apply(x: jax.Array, t: TTLinear) -> jax.Array:
     else:
         assert g0.shape[0] == 1, g0.shape
         g0 = g0[0].astype(jnp.float32)
-    chain_scales = None
-    if t.quantized:
-        g0 = g0 * t.scales[0]
-        chain_scales = [None] + list(t.scales[1:])    # tail stays int8
     chain = [g0] + list(t.cores[1:])
 
     from repro.kernels.tt_contract.ops import tt_contract  # lazy: no cycle
@@ -323,17 +349,15 @@ def tt_apply_experts(x: jax.Array, t: TTLinear) -> jax.Array:
     x3 = x.reshape(e, int(np.prod(batch or (1,))), -1)
 
     # per-expert lead-absorbed first core: (E, r_s)·(r_s, n_1, r_1)
-    lead = t.lead
+    lead, core0 = t.lead, t.cores[0]
     tail_scales = None
     if t.quantized:
         lead = dequantize_array(lead, t.lead_scale, axis=-1)  # (E, r_s)
+        core0 = dequantize_core(core0, t.scales[0])
         tail_scales = list(t.scales[1:])
     g0e = jnp.einsum(
-        "er,rns->ens", lead.astype(jnp.float32),
-        t.cores[0].astype(jnp.float32),
+        "er,rns->ens", lead.astype(jnp.float32), core0.astype(jnp.float32),
     )
-    if t.quantized:
-        g0e = g0e * t.scales[0]
     from repro.kernels.tt_contract.ops import tt_contract_batched
     y3 = tt_contract_batched(x3, g0e, list(t.cores[1:]), split=t.split,
                              scales=tail_scales)

@@ -15,8 +15,10 @@ import numpy as np
 # MXU native tile; VPU lane width.  All kernel tile shapes are multiples.
 MXU_DIM = 128
 VPU_LANES = 128
-# v5e VMEM budget per core we design against (bytes).
-VMEM_BUDGET = 96 * 1024 * 1024
+# Scoped VMEM limit handed to Mosaic on every pallas_call of the TT
+# contraction family, and the bound its dispatch gate admits chains against
+# (v5e has 128 MiB of VMEM per core; the rest is left to the compiler).
+VMEM_LIMIT = 96 * 1024 * 1024
 
 
 def use_interpret() -> bool:
@@ -35,6 +37,17 @@ def pick_tile(dim: int, target: int = MXU_DIM, cap: int = 512) -> int:
     while t * 2 <= cap and dim % (t * 2) == 0:
         t *= 2
     return t
+
+
+def vmem_tile_bytes(shape, dtype) -> int:
+    """VMEM bytes of one array of ``shape`` laid out in native TPU tiles:
+    the minor dim pads to 128 lanes, the second-minor to the dtype's
+    sublane tile (8 rows of 32-bit, 16 of bf16, 32 of int8)."""
+    itemsize = np.dtype(dtype).itemsize
+    dims = (1,) * max(0, 2 - len(shape)) + tuple(int(d) for d in shape)
+    lead = int(np.prod(dims[:-2]))
+    sub = round_up(dims[-2], 8 * max(1, 4 // itemsize))
+    return lead * sub * round_up(dims[-1], VPU_LANES) * itemsize
 
 
 def pad_to(x, rows: int, cols: int):

@@ -5,9 +5,15 @@ representation) and picks the execution path:
 
   * depth 2 (split 1)      → fused ``tt_contract_2``
   * depth 3 (split 1 or 2) → fused ``tt_contract_3``
-  * anything else, or chains whose operands would blow the VMEM budget
-                           → the jnp einsum chain (``tt_contract_ref``),
+  * anything else, or chains whose grid step would not fit
+    ``common.VMEM_LIMIT``  → the jnp einsum chain (``tt_contract_ref``),
                              still unmaterialized, just unfused
+
+The unfused chain is a dispatch decision, not an error path, so it is
+counted: every dispatch bumps ``dispatch_tally()`` under the kernel it
+took, or under ``unfused`` with its reason (``depth`` or ``vmem``).
+Dispatch happens while a program is traced, so the tally counts leaf calls
+per traced program, not per executed step.
 
 All paths return float32 — callers (``core/tt_linear.tt_apply``) cast back
 to the activation dtype after the chain, matching how the dense path's
@@ -16,11 +22,15 @@ einsums accumulate.
 
 from __future__ import annotations
 
+import collections
+import functools
 import os
+import threading
 from typing import Optional, Sequence
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import common
 from repro.kernels.tt_contract import kernel as _kernel
@@ -68,65 +78,90 @@ def _validated_cap(value, source: str) -> int:
     return cap
 
 
-def _core_tile_bytes(g) -> int:
-    """VMEM bytes a resident core tile occupies.  Integer cores pass into
-    the kernels in their storage dtype (the fused dequant widens them only
-    as compute values, never as a resident tile), so they cost itemsize
-    bytes; float cores are pre-cast to f32 at the entry point, so their
-    resident cost is 4 bytes regardless of the caller-side dtype."""
-    if jnp.issubdtype(jnp.dtype(g.dtype), jnp.integer):
-        return int(g.size) * jnp.dtype(g.dtype).itemsize
-    return int(g.size) * 4
+def _wide(g):
+    """The dtype a core has inside the kernel: integer cores ride in their
+    storage dtype, float cores are cast to f32 by the kernel entry point."""
+    dt = jnp.dtype(g.dtype)
+    return dt if jnp.issubdtype(dt, jnp.integer) else jnp.dtype(jnp.float32)
+
+
+def _vmem_parts(x2, cores, n_out: int, split: int,
+                tile_cap: int = _kernel.DEFAULT_TILE_CAP):
+    """(block bytes, value bytes) of one grid step at the tile ``_grid_1d``
+    will pick.  Blocks are the activation tile in and out, every core at
+    its in-kernel dtype and the scale operand; values are the f32 partial
+    products the body holds at once and, for integer cores, their widened
+    copies.  Every array is counted in native VMEM tiles
+    (``common.vmem_tile_bytes``), which pad small rank dims to 128 lanes."""
+    f32 = jnp.float32
+    tb = common.vmem_tile_bytes
+    bb = _kernel._grid_1d(x2.shape[0], tile_cap)
+    n_in = x2.shape[1]
+    g0 = cores[0]
+    n1, r1 = g0.shape
+    blocks = tb((n1, r1), f32)
+    values = tb((bb, r1), f32)
+    if len(cores) == 2:
+        g1 = cores[1]
+        blocks += tb((r1, n_out), _wide(g1)) + tb((bb, n_in), f32)
+        blocks += tb((bb, n_out), f32)
+        values += tb((bb, n_out), f32)
+        if _wide(g1) != f32:
+            values += tb((r1, n_out), f32)
+    else:
+        g1, g2 = cores[1], cores[2]
+        n_mid, r2 = g1.shape[1], g1.shape[2]
+        n3 = g2.shape[1]
+        blocks += tb((n_mid, r1, r2), _wide(g1)) + tb((r2, n3), _wide(g2))
+        if split == 1:
+            blocks += tb((bb, n_in), f32) + tb((n_mid, bb, n3), f32)
+        else:
+            blocks += tb((n_mid, bb, n_in // n_mid), f32) + tb((bb, n3), f32)
+        values += tb((bb, r2), f32) + tb((bb, n3), f32)
+        if _wide(g1) != f32:
+            values += tb((r1, r2), f32) + tb((r2, n3), f32)
+    if any(_wide(g) != f32 for g in cores[1:]):         # (1, r) row scales
+        blocks += sum(tb((1, g.shape[0]), f32) for g in cores[1:])
+    return blocks, values
+
+
+def _vmem_bytes(x2, cores, n_out: int, split: int,
+                tile_cap: int = _kernel.DEFAULT_TILE_CAP) -> int:
+    """VMEM bytes of one grid step.  Pallas may double-buffer every block,
+    resident cores included, so blocks count twice: an upper bound (on v5e
+    the compiler took ``tt_contract_2`` up to about 2.5x the rank this
+    admits at the same limit), so a chain the gate admits compiles."""
+    blocks, values = _vmem_parts(x2, cores, n_out, split, tile_cap)
+    return 2 * blocks + values
 
 
 def _fits_vmem(x2, cores, n_out: int, split: int,
                tile_cap: int = _kernel.DEFAULT_TILE_CAP) -> bool:
-    """Bytes of one grid step at the tile _grid_1d will actually pick:
-    activation tile in + out, cores fully resident, PLUS the largest
-    intermediate the fused body materializes — the depth-3 expand path's
-    ``(bb, n_mid·r2)`` tile can dwarf both activation tiles and used to be
-    unaccounted, letting oversized chains onto the fused path.
-
-    Activation tiles and intermediates are always f32 (4 bytes); cores are
-    accounted at their per-element itemsize — an int8 core tile is a
-    quarter the f32 footprint, and assuming uniform 4-byte elements here
-    would wrongly bounce near-budget quantized chains off the fused path
-    (and, symmetrically, would under-gate if wide intermediates were ever
-    accounted at a narrow itemsize)."""
-    bb = _kernel._grid_1d(x2.shape[0], tile_cap)
-    n_in = x2.shape[1]
-    if len(cores) == 2:
-        interm = bb * cores[0].shape[1]                   # t = x·g0 (bb, r1)
-    else:
-        r1 = cores[0].shape[1]
-        n_mid, r2 = cores[1].shape[1], cores[1].shape[2]
-        # producer and consumer tiles are live at the same time inside the
-        # fused body, so they SUM (a max() here would repeat the original
-        # under-count one level down)
-        if split == 1:
-            interm = bb * (r1 + n_mid * r2)               # t1 + (bb,n_mid·r2)
-        else:
-            # transposed x copy (bb·n_mid, n1) + partial (bb·n_mid, r1)
-            # + contracted (bb, r2)
-            interm = bb * (n_in + n_mid * r1 + r2)
-    ops_bytes = (4 * (bb * (n_in + n_out) + interm)
-                 + sum(_core_tile_bytes(g) for g in cores))
-    return ops_bytes < common.VMEM_BUDGET // 2
+    """The dispatch gate: one grid step fits the limit the kernels hand
+    the compiler (``common.VMEM_LIMIT``) — one number for both."""
+    return _vmem_bytes(x2, cores, n_out, split, tile_cap) <= common.VMEM_LIMIT
 
 
-def _combined_scale(scales) -> Optional[jax.Array]:
-    """Product of the non-``None`` per-core dequant scales, or ``None`` when
-    the chain is unquantized.  The TT chain is linear in every core, so the
-    per-core symmetric scales commute out to one output multiply."""
-    if scales is None:
-        return None
-    combined = None
-    for s in scales:
-        if s is None:
-            continue
-        s = jnp.asarray(s, jnp.float32)
-        combined = s if combined is None else combined * s
-    return combined
+_TALLY: collections.Counter = collections.Counter()
+_TALLY_LOCK = threading.Lock()
+
+
+def _count(route: str, reason: str = "") -> None:
+    with _TALLY_LOCK:
+        _TALLY[(route, reason)] += 1
+
+
+def dispatch_tally() -> dict:
+    """``{(route, reason): count}`` of the dispatches traced so far: route
+    is the fused kernel's name, or ``"unfused"`` with reason ``"depth"``
+    (no fused body for this depth/split) or ``"vmem"`` (gate refused)."""
+    with _TALLY_LOCK:
+        return dict(_TALLY)
+
+
+def reset_dispatch_tally() -> None:
+    with _TALLY_LOCK:
+        _TALLY.clear()
 
 
 def tt_contract(
@@ -139,67 +174,62 @@ def tt_contract(
 ) -> jax.Array:                     # (B, N_out) float32
     """Contract activations straight through TT cores (no dense weight).
 
-    ``scales`` (aligned with ``cores``, ``None`` entries = already-wide
-    cores) selects the dequant-fused kernels: integer cores ride into the
-    kernel in storage dtype and the scale product folds into the output
-    tile.  The unfused fallback dequantizes via the same linearity —
-    ``tt_contract_ref(x, cores) * ∏scales``."""
+    ``scales`` (aligned with ``cores``: a core's (r,) row scales, ``None``
+    for the already-wide first core) selects the dequant-fused kernels:
+    integer cores ride into the kernel in storage dtype and each core's row
+    scales multiply the partial product that meets it.  The unfused
+    fallback contracts the explicitly dequantized chain."""
     if interpret is None:
         interpret = common.use_interpret()
     depth = len(cores)
     x2 = x2.astype(jnp.float32)
-    combined = _combined_scale(scales)
+    quantized = scales is not None
     n_out = 1
     for g in cores[split:]:
         n_out *= g.shape[1]
+    fusable = depth == 2 and split == 1 or depth == 3 and split in (1, 2)
     # first candidate cap whose grid-step footprint clears the VMEM gate
     cap = None
-    for c in resolve_tile_cap(x2.shape[0], tile):
+    for c in resolve_tile_cap(x2.shape[0], tile) if fusable else ():
         if _fits_vmem(x2, cores, n_out, split, c):
             cap = c
             break
+    if cap is None:
+        _count("unfused", "vmem" if fusable else "depth")
+        if quantized:
+            cores = tt_dequant_chain(cores, scales)
+        return tt_contract_ref(x2, cores, split)
 
-    if depth == 2 and split == 1 and cap is not None:
+    # row scales ride in as (1, r) rows that broadcast over a partial product
+    rows = ([jnp.asarray(sc, jnp.float32).reshape(1, -1)
+             for sc in scales[1:]] if quantized else [])
+    if depth == 2:
         g0, g1 = cores
-        g1m = g1[:, :, 0] if g1.ndim == 3 else g1
-        if combined is not None:
-            return _kernel.tt_contract_2q(
-                x2, g0, g1m, combined, interpret=interpret, tile_cap=cap,
-            )
-        return _kernel.tt_contract_2(
-            x2, g0, g1m, interpret=interpret, tile_cap=cap,
-        )
-
-    if depth == 3 and split in (1, 2) and cap is not None:
+        operands = [x2, g0, g1[:, :, 0] if g1.ndim == 3 else g1, *rows]
+        name, kw = "tt_contract_2", {}
+    else:
         g0, g1, g2 = cores
-        g2m = g2[:, :, 0] if g2.ndim == 3 else g2          # (r2, n3)
-        if split == 1:
-            r1, n2, r2 = g1.shape
-            g1f = g1.reshape(r1, n2 * r2)
-            if combined is not None:
-                return _kernel.tt_contract_3q(
-                    x2, g0, g1f, g2m, combined, split=1, n_mid=n2,
-                    n_out=n2 * g2m.shape[1], interpret=interpret,
-                    tile_cap=cap,
-                )
-            return _kernel.tt_contract_3(
-                x2, g0, g1f, g2m, split=1, n_mid=n2,
-                n_out=n2 * g2m.shape[1], interpret=interpret, tile_cap=cap,
-            )
-        r1, n2, r2 = g1.shape
-        g1p = g1.transpose(1, 0, 2).reshape(n2 * r1, r2)   # (n2·r1, r2)
-        if combined is not None:
-            return _kernel.tt_contract_3q(
-                x2, g0, g1p, g2m, combined, split=2, n_mid=n2,
-                n_out=g2m.shape[1], interpret=interpret, tile_cap=cap,
-            )
-        return _kernel.tt_contract_3(
-            x2, g0, g1p, g2m, split=2, n_mid=n2,
-            n_out=g2m.shape[1], interpret=interpret, tile_cap=cap,
-        )
+        operands = [x2, g0, g1.transpose(1, 0, 2),          # (n_mid, r1, r2)
+                    g2[:, :, 0] if g2.ndim == 3 else g2, *rows]
+        name, kw = "tt_contract_3", {"split": split}
+    name += "q" if quantized else ""
+    _count(name)
+    call = functools.partial(getattr(_kernel, name), interpret=interpret,
+                             tile_cap=cap, **kw)
+    return _replicated_on_mesh(call)(*operands)
 
-    y = tt_contract_ref(x2, cores, split)
-    return y if combined is None else y * combined
+
+def _replicated_on_mesh(call):
+    """GSPMD cannot partition a Mosaic kernel: under a multi-device mesh
+    (``jax.set_mesh``) every device runs the whole chain on replicated
+    operands, the activation tile gathered.  This must match the placement
+    ``sharding.place_params`` gives TT leaves (replicated whole); when a
+    rule there shards a TT chain, the specs come from that rule and this
+    wrapper goes."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return call
+    return jax.shard_map(call, in_specs=P(), out_specs=P(), check_vma=False)
 
 
 def tt_contract_batched(
@@ -220,8 +250,8 @@ def tt_contract_batched(
     VMEM gate applies per grid step, which is exactly the per-expert tile.
 
     ``scales`` aligns with the shared tail ``cores`` (the per-expert lead is
-    handed in wide, its per-row scales folded by the caller), so the scale
-    product is expert-invariant and closes over the vmap unbatched."""
+    handed in wide, its scales folded by the caller), so the row scales are
+    expert-invariant and close over the vmap unbatched."""
     rest = list(cores)
     chain_scales = None if scales is None else [None] + list(scales)
     return jax.vmap(
@@ -232,7 +262,8 @@ def tt_contract_batched(
 
 
 __all__ = [
-    "resolve_tile_cap", "tt_contract", "tt_contract_batched",
+    "dispatch_tally", "reset_dispatch_tally", "resolve_tile_cap",
+    "tt_contract", "tt_contract_batched",
     "tt_contract_batched_ref", "tt_contract_ref", "tt_dense_ref",
     "tt_dequant_chain",
 ]

@@ -75,18 +75,20 @@ def tt_dequant_chain(
     cores: Sequence[jax.Array],
     scales: Sequence[jax.Array | None],
 ) -> list[jax.Array]:
-    """Explicitly dequantize a chain: each core widened to f32 and multiplied
-    by its symmetric scale (``None`` = core is already wide — e.g. the
-    lead-absorbed first core whose scale was folded host-side).  This is the
-    unfused oracle the scale-folded kernels must match at f32 tolerance: the
-    chain is linear in every core, so scaling cores individually and scaling
-    the output once by the product are the same map."""
+    """Explicitly dequantize a chain: each core widened to f32 and each of
+    its leading-rank rows multiplied by its symmetric scale (``None`` = core
+    is already wide — e.g. the lead-absorbed first core whose scales were
+    folded host-side).  This is the unfused oracle the scale-folding kernels
+    must match at f32 tolerance: the chain is linear in every core, so
+    scaling a core's rows and scaling the matching columns of the partial
+    product are the same map."""
     assert len(cores) == len(scales), (len(cores), len(scales))
     out = []
     for g, s in zip(cores, scales):
         g = jnp.asarray(g, jnp.float32)
         if s is not None:
-            g = g * jnp.asarray(s, jnp.float32)
+            s = jnp.asarray(s, jnp.float32)
+            g = g * s.reshape(s.shape + (1,) * (g.ndim - s.ndim))
         out.append(g)
     return out
 

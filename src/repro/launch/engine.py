@@ -336,7 +336,10 @@ class Engine:
     set their own.  Keys advance with slot-local progress only, so
     staggered == isolated holds token-for-token under any mix of per-slot
     params; ``temperature=0`` requests take the greedy argmax
-    (token-identical to an isolated greedy run).
+    (token-identical to an isolated greedy run).  "Isolated" means the
+    request alone at the engine's batch shape (``slots`` rows, its
+    ``max_len``): on a TPU, XLA may compute a row differently at batch 1,
+    so a batch-1 ``generate`` is not the reference there.
 
     Encoder-decoder requests ride slots like any other: ``submit`` takes
     the request's source tokens, admission runs ONE jitted encode

@@ -13,14 +13,13 @@ jax so the placeholder devices exist.
 from __future__ import annotations
 
 import jax
-from jax.sharding import Mesh
-
-from repro import compat
+from jax.sharding import AxisType, Mesh
 
 
 def make_mesh(shape, axes) -> Mesh:
-    """Auto-axis mesh across jax versions (shim: ``repro.compat``)."""
-    return compat.make_mesh(shape, axes)
+    """Mesh with Auto axes: GSPMD propagates shardings from the rules in
+    ``launch/sharding.py`` (no explicit-sharding mode)."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -79,21 +79,6 @@ def _quant_of(weights: str):
     return None
 
 
-def _teacher_forced_logits(model, params, prompts):
-    """Per-position next-token logits, teacher-forced over the prompt via
-    ``decode_step`` -> (b, S-1, V).  The quantized verify line measures
-    agreement here: ``generate``'s prompt_logits is last-position only,
-    far too few samples to state a percentage."""
-    b, s = prompts.shape
-    cache = model.init_cache(b, s)
-    outs = []
-    for t in range(s - 1):
-        logits, cache = model.decode_step(
-            params, cache, jnp.asarray(prompts[:, t:t + 1]))
-        outs.append(np.asarray(logits, np.float32).reshape(b, -1))
-    return np.stack(outs, 1)
-
-
 def _tt_setup(params, args, cfg):
     """Compress (or load) the TT payload and build the TT-native params.
 
@@ -233,16 +218,16 @@ def serve(args) -> dict:
                 # any perturbation) — report the GATED metric instead:
                 # teacher-forced tie-tolerant agreement over every prompt
                 # position (see benchmarks/tt_serve.run_quant)
-                tf_q = _teacher_forced_logits(model, params, prompts)
-                tf_rx = _teacher_forced_logits(model, params_rx, prompts)
-                tol = 0.05 * float(np.max(np.abs(tf_rx)))
-                top = np.argmax(tf_rx, -1)
-                deficit = np.max(tf_q, -1) - np.take_along_axis(
-                    tf_q, top[..., None], -1)[..., 0]
+                from repro.models.common import (
+                    teacher_forced_logits, tie_tolerant_agreement,
+                )
+                tf_q = teacher_forced_logits(model, params, prompts)
+                tf_rx = teacher_forced_logits(model, params_rx, prompts)
                 agree_line = (
                     f"tie-tolerant next-token agreement "
-                    f"{float(np.mean(deficit <= tol)):.2%} over "
-                    f"{top.size} teacher-forced positions")
+                    f"{tie_tolerant_agreement(tf_q, tf_rx):.2%} over "
+                    f"{tf_rx.shape[0] * tf_rx.shape[1]} teacher-forced "
+                    f"positions")
             print(f"[serve] verify vs reconstruct-then-serve: "
                   f"max|Δlogits| {d:.2e} (scale {scale:.2e}), "
                   f"{agree_line}, "
@@ -387,6 +372,8 @@ def main() -> None:
                          "dispatch per admission between chunks (encdec); "
                          "'auto' picks per family")
     args = ap.parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.serve:
         serve_http(args)
     else:

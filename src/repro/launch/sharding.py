@@ -153,6 +153,23 @@ def param_specs(params_shape, cfg, model_axis="model", fsdp_axis="data"):
     return jax.tree_util.tree_unflatten(treedef, specs)
 
 
+def place_params(params, cfg, mesh: Mesh):
+    """``device_put`` a params pytree onto ``mesh`` under the rules above.
+
+    TT-native leaves (``TTLinear``) are replicated whole: no rule shards a
+    TT chain, and their cores are the compressed payload.  Dense leaves
+    (the embedding included) take their ``spec_for`` sharding."""
+    from repro.core.tt_linear import is_tt_linear
+
+    def one(path, leaf):
+        if is_tt_linear(leaf):
+            return jax.device_put(leaf, NamedSharding(mesh, P()))
+        spec = spec_for(_path_str(path), leaf.shape, cfg)
+        return jax.device_put(leaf, NamedSharding(mesh, spec))
+
+    return jax.tree_util.tree_map_with_path(one, params, is_leaf=is_tt_linear)
+
+
 def batch_specs(batch_shape, data_axes=("pod", "data")):
     """Batch pytree: leading dim over (pod, data); embeddings stubs too.
     Dims that don't divide the axis product (e.g. batch=1 at long_500k)

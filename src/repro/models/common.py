@@ -128,7 +128,12 @@ def dense_apply(x: jax.Array, w, in_ndim: int = 1) -> jax.Array:
         tuple(range(x.ndim - in_ndim, x.ndim)),
         tuple(range(in_ndim)),
     )
-    return jax.lax.dot_general(x, w, (cdims, ((), ())))
+    # accumulate in at least f32 and round once: where the contraction is
+    # sharded (row-parallel on a model mesh), the partial sums then meet in
+    # f32 and the result matches one device's single rounding
+    acc = jnp.promote_types(x.dtype, jnp.float32)
+    return jax.lax.dot_general(x, w, (cdims, ((), ())),
+                               preferred_element_type=acc).astype(x.dtype)
 
 
 def expert_apply(x: jax.Array, w) -> jax.Array:
@@ -798,6 +803,31 @@ def logit_parity(a: jax.Array, b: jax.Array) -> Tuple[float, float, float]:
         (jnp.argmax(a, -1) == jnp.argmax(b, -1)).astype(jnp.float32)
     ))
     return d, scale, agree
+
+
+def teacher_forced_logits(model, params, prompts) -> np.ndarray:
+    """Per-position next-token logits, teacher-forced over ``prompts``
+    (B, S) one jitted ``decode_step`` per position -> (B, S-1, V) f32.
+    ``generate``'s prompt_logits is last-position only, far too few samples
+    to state an agreement percentage."""
+    b, s = prompts.shape
+    step = jax.jit(model.decode_step)
+    cache = model.init_cache(b, s)
+    outs = []
+    for t in range(s - 1):
+        logits, cache = step(params, cache, jnp.asarray(prompts[:, t:t + 1]))
+        outs.append(np.asarray(logits, np.float32).reshape(b, -1))
+    return np.stack(outs, 1)
+
+
+def tie_tolerant_agreement(lq, lref, tol: float = 0.05) -> float:
+    """Share of positions where ``lq`` scores the reference's argmax token
+    within ``tol`` x max|lref| of its own max — the quantized-serving gate
+    (>= 0.99).  Exact argmax agreement would flip at near-tied positions,
+    where synthetic spectral-decay weights put many of them."""
+    top = np.argmax(lref, -1)
+    deficit = np.max(lq, -1) - np.take_along_axis(lq, top[..., None], -1)[..., 0]
+    return float(np.mean(deficit <= tol * float(np.max(np.abs(lref)))))
 
 
 def dense_init(key, shape, in_axis: int = 0, dtype=jnp.bfloat16):

@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 from repro.models import common
 
 
@@ -205,8 +204,8 @@ def moe_apply_a2a(x, p: MoEParams, cfg, capacity_factor: float = 1.25):
 
     bspec = batch_axes if len(batch_axes) > 1 else (batch_axes[0]
                                                     if batch_axes else None)
-    return compat.shard_map(
-        body, mesh=mesh,
+    return jax.shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P(bspec, None, None),
                   P(f_ax, None),
                   P("model", f_ax, None),

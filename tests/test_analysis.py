@@ -171,7 +171,7 @@ def test_prg001_fires_on_weight_sized_const():
 
 
 def test_prg001_fires_on_f64():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         fs = programlint._check_dtypes(
             _report(lambda x: x * 2.0, jnp.ones((4,), jnp.float64)))
     assert _ids(fs) == ["PRG001"]

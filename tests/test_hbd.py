@@ -6,6 +6,7 @@ import pytest
 
 from repro.core.hbd import (
     bidiagonal_bands,
+    hbd_reduce,
     house,
     house_mm_update,
     householder_bidiagonalize,
@@ -18,6 +19,7 @@ SHAPES = [(8, 8), (12, 7), (16, 5), (5, 5), (30, 20), (64, 48), (33, 17)]
 def test_reconstruction(rng, m, n):
     a = rng.standard_normal((m, n)).astype(np.float32)
     ub, b, vbt = householder_bidiagonalize(jnp.asarray(a))
+    assert ub.shape == (m, n) and vbt.shape == (n, n)
     rec = np.asarray(ub) @ np.asarray(b) @ np.asarray(vbt)
     np.testing.assert_allclose(rec, a, atol=5e-5 * np.sqrt(m * n))
 
@@ -27,7 +29,7 @@ def test_orthogonality(rng, m, n):
     a = rng.standard_normal((m, n)).astype(np.float32)
     ub, _, vbt = householder_bidiagonalize(jnp.asarray(a))
     np.testing.assert_allclose(
-        np.asarray(ub) @ np.asarray(ub).T, np.eye(m), atol=2e-5
+        np.asarray(ub).T @ np.asarray(ub), np.eye(n), atol=2e-5
     )
     np.testing.assert_allclose(
         np.asarray(vbt) @ np.asarray(vbt).T, np.eye(n), atol=2e-5
@@ -39,11 +41,29 @@ def test_bidiagonal_structure(rng, m, n):
     a = rng.standard_normal((m, n)).astype(np.float32)
     _, b, _ = householder_bidiagonalize(jnp.asarray(a), compute_uv=False)
     bb = np.asarray(b).copy()
+    assert bb.shape == (n, n)                       # thin: rows N..M are zero
     for i in range(min(m, n)):
         bb[i, i] = 0.0
         if i + 1 < n:
             bb[i, i + 1] = 0.0
     assert np.abs(bb).max() == 0.0
+
+
+@pytest.mark.parametrize("m,n", [(12, 7), (33, 17)])
+def test_thin_u_is_leading_columns_of_reflector_product(rng, m, n):
+    """The thin U_B is the first N columns of H_0 H_1 ... H_{N-1}, each
+    reflector rebuilt from the vectors the reduction stores in place."""
+    a = rng.standard_normal((m, n)).astype(np.float32)
+    a_red, diag, _ = (np.asarray(x, np.float64)
+                      for x in hbd_reduce(jnp.asarray(a)))
+    full = np.eye(m)
+    for i in range(n):
+        v = np.where(np.arange(m) >= i, a_red[:, i], 0.0)
+        beta = v[i] * diag[i]
+        h = np.eye(m) + (np.outer(v, v) / beta if beta != 0 else 0.0)
+        full = full @ h
+    ub, _, _ = householder_bidiagonalize(jnp.asarray(a))
+    np.testing.assert_allclose(np.asarray(ub), full[:, :n], atol=2e-5)
 
 
 def test_house_matches_paper_eq3(rng):

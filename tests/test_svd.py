@@ -6,23 +6,29 @@ import pytest
 
 from repro.core.bidiag_qr import bidiag_svd_values, jacobi_svd_values
 from repro.core.hbd import bidiagonal_bands, householder_bidiagonalize
-from repro.core.svd import sorting_basis, svd, svd_reconstruct
+from repro.core.svd import sorting_basis, svd, svd_host_diag, svd_reconstruct
 
 SHAPES = [(24, 24), (40, 16), (16, 40), (50, 30), (7, 13)]
+# phase 2 in the graph (batched/in-graph paths) or on the host's LAPACK
+# (the serial TT-SVD): same contract
+TWO_PHASE = {"in_graph": lambda a: svd(a, method="two_phase"),
+             "host_diag": svd_host_diag}
 
 
+@pytest.mark.parametrize("impl", sorted(TWO_PHASE))
 @pytest.mark.parametrize("m,n", SHAPES)
-def test_two_phase_values_match_library(rng, m, n):
+def test_two_phase_values_match_library(rng, m, n, impl):
     a = rng.standard_normal((m, n)).astype(np.float32)
-    r = svd(jnp.asarray(a), method="two_phase")
+    r = TWO_PHASE[impl](jnp.asarray(a))
     s_ref = np.linalg.svd(a, compute_uv=False)
     np.testing.assert_allclose(np.asarray(r.s), s_ref, atol=2e-5 * s_ref[0])
 
 
+@pytest.mark.parametrize("impl", sorted(TWO_PHASE))
 @pytest.mark.parametrize("m,n", SHAPES)
-def test_two_phase_reconstructs(rng, m, n):
+def test_two_phase_reconstructs(rng, m, n, impl):
     a = rng.standard_normal((m, n)).astype(np.float32)
-    r = svd(jnp.asarray(a), method="two_phase")
+    r = TWO_PHASE[impl](jnp.asarray(a))
     np.testing.assert_allclose(
         np.asarray(svd_reconstruct(r)), a, atol=5e-5 * np.sqrt(m * n)
     )

@@ -4,7 +4,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core import quantize_array
+from repro.core.tt_linear import quantize_core
 from repro.kernels.tt_contract.ops import (
     tt_contract, tt_contract_batched, tt_contract_batched_ref,
     tt_contract_ref, tt_dense_ref, tt_dequant_chain,
@@ -138,10 +138,10 @@ def test_tt_contract_batched_matches_ref_and_dense(rng, mode_dims, ranks,
 
 def _quantize_tail(cores):
     """TTLinear-style quantized chain: wide lead-absorbed first core (its
-    scale folded host-side), int8 tail cores + per-core scales."""
+    scales folded host-side), int8 tail cores + per-row scales."""
     qcores, scales = [cores[0]], [None]
     for g in cores[1:]:
-        q, s = quantize_array(g)
+        q, s = quantize_core(g)
         qcores.append(q)
         scales.append(s)
     return qcores, scales
@@ -174,15 +174,15 @@ def test_tt_contract_quantized_matches_dequant_ref(rng, mode_dims, ranks,
 def test_tt_contract_batched_quantized_vs_per_expert(rng, mode_dims, ranks,
                                                      split):
     """Quantized expert-batched chain == per-expert dequantize-then-contract
-    loop: experts share the int8 tail cores and their scales, so the scale
-    product is expert-invariant."""
+    loop: experts share the int8 tail cores and their row scales, so the
+    scales are expert-invariant."""
     e, b = 4, 6
     g0b = jnp.asarray(
         rng.standard_normal((e, mode_dims[0], ranks[0])), jnp.float32)
     rest = _mk_chain(rng, mode_dims, ranks)[1:]
     qrest, tail_scales = [], []
     for g in rest:
-        q, s = quantize_array(g)
+        q, s = quantize_core(g)
         qrest.append(q)
         tail_scales.append(s)
     n_in = int(np.prod(mode_dims[:split]))
@@ -201,29 +201,29 @@ def test_tt_contract_batched_quantized_vs_per_expert(rng, mode_dims, ranks,
 
 def test_fits_vmem_accounts_core_itemsize(rng, monkeypatch):
     """Regression: the VMEM gate assumed 4 bytes per core element.  An int8
-    chain near the budget occupies a quarter of that — the old accounting
-    would bounce it off the fused path it actually fits on.  Craft a budget
-    between the int8 and the (hypothetical) uniform-f32 footprint: the
-    quantized chain must pass the gate and dispatch fused, the wide chain
-    must fail it."""
+    chain near the limit occupies less than its wide twin — the old
+    accounting would bounce it off the fused path it actually fits on.
+    Craft a limit between the int8 and the wide footprint of the SAME chain:
+    the quantized chain must pass the gate and dispatch fused, the wide
+    chain must fail it."""
     from repro.kernels import common as kcommon
     from repro.kernels.tt_contract import kernel as kernel_mod
     from repro.kernels.tt_contract import ops
 
-    cores = _mk_chain(rng, [64, 128], [16])        # tail core 16*128 elements
+    cores = _mk_chain(rng, [64, 512], [64])        # tail core 64*512 elements
     qcores, scales = _quantize_tail(cores)
     x = jnp.asarray(rng.standard_normal((32, 64)), jnp.float32)
-    bb = kernel_mod._grid_1d(32)
-    n_out = 128
-    acts = 4 * (bb * (64 + n_out) + bb * 16)       # tiles + (bb, r1) interm
-    wide_cores = 4 * sum(int(g.size) for g in cores)
-    int8_cores = sum(
-        int(g.size) * (1 if g.dtype == jnp.int8 else 4) for g in qcores
-    )
-    # budget straddles the two accountings of the SAME chain
-    budget = 2 * (acts + (int8_cores + wide_cores) // 2)
-    assert acts + int8_cores < budget // 2 < acts + wide_cores
-    monkeypatch.setattr(kcommon, "VMEM_BUDGET", budget)
+    n_out = 512
+    q_bytes = ops._vmem_bytes(x, qcores, n_out, 1)
+    wide_bytes = ops._vmem_bytes(x, cores, n_out, 1)
+    # the int8 block is a quarter of the f32 one; its widened copy is a
+    # value, counted once, not a double-buffered block; its (1, 64) row
+    # scales are one more (double-buffered) block
+    tail = 64 * 512
+    scale_block = kcommon.vmem_tile_bytes((1, 64), jnp.float32)
+    assert wide_bytes - q_bytes == (2 * (4 - 1) * tail - 4 * tail
+                                    - 2 * scale_block)
+    monkeypatch.setattr(kcommon, "VMEM_LIMIT", (q_bytes + wide_bytes) // 2)
 
     assert ops._fits_vmem(x, qcores, n_out, split=1)
     assert not ops._fits_vmem(x, cores, n_out, split=1)
@@ -251,25 +251,24 @@ def test_fits_vmem_accounts_core_itemsize(rng, monkeypatch):
 # ---------------------------------------------------------------------------
 
 def test_fits_vmem_counts_depth3_intermediate(rng, monkeypatch):
-    """Regression: a chain whose (bb, n_mid*r2) intermediate pushes the
-    fused tile just past the budget must fall back to tt_contract_ref —
-    the old accounting (acts + cores only) would have fused it."""
+    """Regression: a chain whose live f32 values (the per-mode partial
+    products of the depth-3 body) push the grid step just past the limit
+    must fall back to tt_contract_ref — accounting blocks alone would have
+    fused it.  Blocks count double-buffered, values once."""
     from repro.kernels import common as kcommon
     from repro.kernels.tt_contract import kernel as kernel_mod
     from repro.kernels.tt_contract import ops
 
-    cores = _mk_chain(rng, [8, 16, 4], [4, 8])     # n_mid*r2 = 128
+    cores = _mk_chain(rng, [8, 16, 4], [4, 8])
     x = jnp.asarray(rng.standard_normal((32, 8)), jnp.float32)
-    bb = kernel_mod._grid_1d(32)
     n_out = 16 * 4
-    acts_and_cores = 4 * (bb * (8 + n_out) + sum(int(g.size) for g in cores))
-    interm = 4 * bb * 16 * 8                       # the unaccounted tile
-    # budget between the old and the corrected footprint: old accounting
-    # says "fits", corrected says "doesn't"
-    budget = 2 * (acts_and_cores + interm // 2)
-    assert acts_and_cores < budget // 2 < acts_and_cores + interm
-    monkeypatch.setattr(kcommon, "VMEM_BUDGET", budget)
-
+    blocks, values = ops._vmem_parts(x, cores, n_out, 1)
+    bb = kernel_mod._grid_1d(32)
+    # (bb, r1) + (bb, r2) + (bb, n3) f32 tiles, each padded to 128 lanes
+    assert values == 3 * bb * 128 * 4
+    assert ops._vmem_bytes(x, cores, n_out, 1) == 2 * blocks + values
+    # limit between blocks-only and the full footprint: refused
+    monkeypatch.setattr(kcommon, "VMEM_LIMIT", 2 * blocks + values // 2)
     assert not ops._fits_vmem(x, cores, n_out, split=1)
 
     def boom(*a, **k):                             # fused path must not run
@@ -282,11 +281,48 @@ def test_fits_vmem_counts_depth3_intermediate(rng, monkeypatch):
         y, y_dense, atol=1e-5 * max(np.abs(y_dense).max(), 1e-6)
     )
 
-    # control: with the intermediate inside the budget the fused path runs
-    monkeypatch.setattr(
-        kcommon, "VMEM_BUDGET", 4 * (acts_and_cores + 2 * interm)
-    )
+    # control: with the values inside the limit the fused path runs
+    monkeypatch.setattr(kcommon, "VMEM_LIMIT", 2 * blocks + values)
     assert ops._fits_vmem(x, cores, n_out, split=1)
+
+
+def test_fits_vmem_counts_double_buffered_blocks(rng, monkeypatch):
+    """Pallas may hold two buffers of every block, resident cores included:
+    a limit that fits one copy of the blocks but not two is refused."""
+    from repro.kernels import common as kcommon
+    from repro.kernels.tt_contract import ops
+
+    cores = _mk_chain(rng, [128, 256], [64])
+    x = jnp.asarray(rng.standard_normal((16, 128)), jnp.float32)
+    blocks, values = ops._vmem_parts(x, cores, 256, 1)
+    monkeypatch.setattr(kcommon, "VMEM_LIMIT", blocks + values)
+    assert not ops._fits_vmem(x, cores, 256, split=1)
+    monkeypatch.setattr(kcommon, "VMEM_LIMIT", 2 * blocks + values)
+    assert ops._fits_vmem(x, cores, 256, split=1)
+
+
+def test_dispatch_tally_counts_unfused(rng, monkeypatch):
+    """The unfused chain is a counted dispatch decision: a depth-4 chain is
+    tallied under ``depth``, a gate-refused depth-3 chain under ``vmem``,
+    and a fused call under its kernel's name."""
+    from repro.kernels import common as kcommon
+    from repro.kernels.tt_contract import ops
+
+    ops.reset_dispatch_tally()
+    deep = _mk_chain(rng, [8, 16, 16, 16], [3, 5, 7])
+    ops.tt_contract(jnp.ones((8, 8 * 16), jnp.float32), deep, 2)
+    wide = _mk_chain(rng, [64, 4, 32], [5, 9])
+    x = jnp.ones((8, 64), jnp.float32)
+    ops.tt_contract(x, wide, 1)
+    monkeypatch.setattr(kcommon, "VMEM_LIMIT", 1024)
+    ops.tt_contract(x, wide, 1)
+    assert ops.dispatch_tally() == {
+        ("unfused", "depth"): 1,
+        ("tt_contract_3", ""): 1,
+        ("unfused", "vmem"): 1,
+    }
+    ops.reset_dispatch_tally()
+    assert ops.dispatch_tally() == {}
 
 
 # ---------------------------------------------------------------------------
@@ -364,11 +400,12 @@ def test_bigger_default_cap_never_evicts_fused(rng, monkeypatch):
     cores = _mk_chain(rng, [64, 128], [4])
     x = jnp.asarray(rng.standard_normal((2048, 64)), jnp.float32)
     n_out = 128
-    # budget between the bb=1024 footprint and the bb=512 one
+    # limit between the bb=1024 footprint and the bb=512 one
     assert ops._fits_vmem(x, cores, n_out, 1, 512)
-    hi = 4 * (1024 * (64 + 128 + 4) + sum(int(g.size) for g in cores))
-    lo = 4 * (512 * (64 + 128 + 4) + sum(int(g.size) for g in cores))
-    monkeypatch.setattr(kcommon, "VMEM_BUDGET", (hi + lo))  # lo < B/2 < hi
+    hi = ops._vmem_bytes(x, cores, n_out, 1, 1024)
+    lo = ops._vmem_bytes(x, cores, n_out, 1, 512)
+    assert lo < hi
+    monkeypatch.setattr(kcommon, "VMEM_LIMIT", (hi + lo) // 2)
     assert not ops._fits_vmem(x, cores, n_out, 1, 2048)
     assert not ops._fits_vmem(x, cores, n_out, 1, 1024)
     assert ops._fits_vmem(x, cores, n_out, 1, 512)

@@ -25,6 +25,7 @@ from repro.core import (
     tt_reconstruct,
     ttd,
 )
+from repro.core.tt_linear import dequantize_core, quantize_core
 from repro.models import common as model_common
 
 
@@ -281,6 +282,21 @@ def test_quantize_roundtrip_error_bound(rng):
     np.testing.assert_array_equal(np.asarray(dequantize_array(qz, sz)), 0.0)
 
 
+def test_dequantize_core_reads_per_core_scale(rng):
+    """A core quantized with one scale for the whole core (int8 checkpoints
+    written before row scales) dequantizes with that scale."""
+    g = jnp.asarray(rng.standard_normal((6, 5, 4)), jnp.float32)
+    q, s = quantize_array(g)
+    assert np.ndim(s) == 0
+    np.testing.assert_array_equal(np.asarray(dequantize_core(q, s)),
+                                  np.asarray(dequantize_array(q, s)))
+    qr, sr = quantize_core(g)
+    assert sr.shape == (6,)
+    np.testing.assert_array_equal(
+        np.asarray(dequantize_core(qr, sr)),
+        np.asarray(qr, np.float32) * np.asarray(sr)[:, None, None])
+
+
 def test_quantize_tt_roundtrip_and_idempotence(rng):
     """dequantize_tt inverts to the grid; requantizing the dequantized form
     (absmax calibration) is bit-identical — the property the int8
@@ -414,13 +430,14 @@ def test_tt_payload_checkpoint_quantized_roundtrip(rng, tmp_path):
         loaded, is_leaf=lambda x: hasattr(x, "kind")) if c.kind == "tt"]
     assert len(cp0) == len(cp1) == 1
     for g0, g1 in zip(cp0[0].tt.cores, cp1[0].tt.cores):
-        q0, s0 = quantize_array(jnp.asarray(g0, jnp.float32))
-        q1, s1 = quantize_array(jnp.asarray(g1, jnp.float32))
+        q0, s0 = quantize_core(jnp.asarray(g0, jnp.float32))
+        q1, s1 = quantize_core(jnp.asarray(g1, jnp.float32))
+        assert s0.shape == (g0.shape[0],)             # one scale per row
         np.testing.assert_array_equal(np.asarray(q0), np.asarray(q1))
         np.testing.assert_array_equal(np.asarray(s0), np.asarray(s1))
         # loaded core == dequantized form of the saved one (grid-exact)
         np.testing.assert_array_equal(
-            np.asarray(g1), np.asarray(dequantize_array(q0, s0))
+            np.asarray(g1), np.asarray(dequantize_core(q0, s0))
         )
 
 
