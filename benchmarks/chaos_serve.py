@@ -24,9 +24,9 @@ in the prompt → non-finite logits → quarantine), and a corrupt/truncated
 checkpoint leg (sha256 verification must name the bad leaf; stale tmp
 dirs must be cleaned).
 
-Results land in ``BENCH_chaos.json``: injected/fired fault counts,
-outcome histogram, recovery time, survivor parity, retry outcomes, and
-the checkpoint-integrity checklist.
+It prints injected/fired fault counts, the outcome histogram, recovery
+time, survivor parity, retry outcomes, and the checkpoint-integrity
+checklist.
 """
 
 from __future__ import annotations
@@ -227,7 +227,6 @@ def _checkpoint_leg() -> dict:
 def run(fast: bool = False, arch: str = "qwen1.5-0.5b", seed: int = 0):
     import jax
 
-    from benchmarks.record import write_bench
     from repro.configs import get_config
     from repro.launch.engine import Engine
     from repro.launch.router import Router
@@ -354,7 +353,6 @@ def run(fast: bool = False, arch: str = "qwen1.5-0.5b", seed: int = 0):
     if ckpt:
         assert all(ckpt.values()), f"checkpoint integrity leg failed: {ckpt}"
 
-    write_bench("chaos", results)
     return results
 
 

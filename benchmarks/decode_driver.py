@@ -19,8 +19,6 @@ Asserts (the CI smoke lane gate):
   * continuous batching beats padded lockstep on aggregate tok/s;
   * encdec requests under continuous batching (encoder memory computed at
     admission) match isolated runs token-for-token.
-
-Results land in ``BENCH_decode.json`` (see benchmarks/record.py).
 """
 
 from __future__ import annotations
@@ -215,7 +213,6 @@ def _continuous_vs_lockstep(model, cfg, params, reqs, slots, chunk_steps):
 
 
 def run(fast: bool = False, arch: str = "gemma3-1b"):
-    from benchmarks.record import write_bench
     from repro.configs import get_config
     from repro.models.registry import build
 
@@ -252,7 +249,6 @@ def run(fast: bool = False, arch: str = "gemma3-1b"):
     assert results["sampled"]["token_parity"], results["sampled"]
     assert results["encdec_continuous"]["token_parity"], (
         results["encdec_continuous"])
-    write_bench("decode", results)
     return results
 
 

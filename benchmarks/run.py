@@ -34,13 +34,6 @@
 ``--fast`` propagates to every benchmark that accepts a ``fast=`` kwarg
 (smaller sweeps, single method) — the CI smoke lane that catches
 benchmark-script rot without paying full benchmark wall-clock.
-
-Headline numbers additionally persist as ``BENCH_<lane>.json`` at the repo
-root (``benchmarks/record.py``) so the perf trajectory is tracked across
-PRs, not just printed: ``decode_driver`` → BENCH_decode.json, ``tt_serve``/
-``tt_families`` → BENCH_tt_serve.json, ``tt_quant`` (and the quantized leg
-of ``tt_families``) → BENCH_tt_quant.json, ``serve_load`` →
-BENCH_serve_load.json, ``chaos`` → BENCH_chaos.json.
 """
 
 from __future__ import annotations

@@ -19,8 +19,6 @@ Measures, per replica tier (N=1 and N=2):
 Asserts: full token parity at every tier, nonzero occupancy, and — on
 multicore hosts only (``os.cpu_count() >= 2``; replica chunks can't
 overlap on one core) — N=2 aggregate req/s >= 1.5x N=1.
-
-Results land in ``BENCH_serve_load.json`` (see benchmarks/record.py).
 """
 
 from __future__ import annotations
@@ -199,7 +197,6 @@ def _run_tier(model, params, trace, expected, replicas, slots, chunk_steps,
 def run(fast: bool = False, arch: str = "qwen1.5-0.5b"):
     import jax
 
-    from benchmarks.record import write_bench
     from repro.configs import get_config
     from repro.models.registry import build
 
@@ -245,7 +242,6 @@ def run(fast: bool = False, arch: str = "qwen1.5-0.5b"):
         assert speedup >= 1.5, (
             f"2 replicas gave {speedup:.2f}x aggregate req/s on "
             f"{cores} cores (expected >= 1.5x)")
-    write_bench("serve_load", results)
     return results
 
 

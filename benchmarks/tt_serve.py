@@ -33,8 +33,7 @@ def _decode(model, params, prompts, gen, max_len, driver="fused"):
     return out["decode_t"], out["prompt_logits"]
 
 
-def run(fast: bool = False, arch: str = "gemma3-1b", eps: float = 0.2,
-        write_json: bool = True):
+def run(fast: bool = False, arch: str = "gemma3-1b", eps: float = 0.2):
     from repro.configs import get_config
     from repro.core import (
         CompressionPolicy, TTCompressor, spectral_decay_pytree,
@@ -92,14 +91,11 @@ def run(fast: bool = False, arch: str = "gemma3-1b", eps: float = 0.2,
     result = {"arch": arch, "max_diff": d, "agreement": agree,
               "dense_bytes": dense_b, "tt_bytes": tt_b,
               "reconstruct_tps": rows[0][1], "tt_native_tps": rows[1][1]}
-    if write_json:
-        from benchmarks.record import write_bench
-        write_bench("tt_serve", {"archs": {arch: result}})
     return result
 
 
 def run_quant(fast: bool = False, arch: str = "gemma3-1b", eps: float = 0.2,
-              write_json: bool = True, min_agreement: float = 0.99,
+              min_agreement: float = 0.99,
               agree_tol: float = 0.05,
               min_vs_tt: float = 1.8, min_vs_dense: float = 3.9):
     """Quantized TT serving gate: int8 cores + fused in-kernel dequant.
@@ -207,9 +203,6 @@ def run_quant(fast: bool = False, arch: str = "gemma3-1b", eps: float = 0.2,
             for mode, tps, bytes_ in rows
         },
     }
-    if write_json:
-        from benchmarks.record import write_bench
-        write_bench("tt_quant", result)
     return result
 
 
@@ -231,16 +224,13 @@ def run_families(fast: bool = False, eps: float = 0.2):
     reconstruct-then-serve and (b) shrink resident weight bytes vs dense —
     the two asserts inside ``run`` — so a family regressing to
     reconstruct-on-load fails the build, not just a benchmark number."""
-    results = [run(fast=fast, arch=arch, eps=eps, write_json=False)
-               for arch in FAMILY_ARCHS]
+    results = [run(fast=fast, arch=arch, eps=eps) for arch in FAMILY_ARCHS]
     print("\nTT-native coverage (family sweep)")
     print(f"{'arch':<24}{'max|Δ|':>10}{'agree':>8}{'byte reduction':>16}")
     for r in results:
         print(f"{r['arch']:<24}{r['max_diff']:>10.2e}"
               f"{r['agreement']:>8.0%}"
               f"{r['dense_bytes'] / r['tt_bytes']:>15.2f}x")
-    from benchmarks.record import write_bench
-    write_bench("tt_serve", {"archs": {r["arch"]: r for r in results}})
     return results
 
 

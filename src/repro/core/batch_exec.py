@@ -25,13 +25,14 @@ Scheduling
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 from typing import Dict, List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import tt as _tt
 from repro.core.plan import Bucket, CompressionPlan
 
@@ -49,9 +50,6 @@ class ExecStats:
     serial_dispatches: int = 0        # SVD dispatches those serial params cost
     batched_params: int = 0           # params decomposed inside bucket launches
     serial_equiv_dispatches: int = 0  # what the all-serial loop would have cost
-    cache_hits: int = 0
-    compiles: int = 0
-    per_bucket: List[Dict] = field(default_factory=list)
 
     @property
     def total_dispatches(self) -> int:
@@ -114,9 +112,6 @@ class BucketExecutor:
         if fn is None:
             fn = _tt.ttd_static_batched.lower(stacked, **statics).compile()
             _EXEC_CACHE[key] = fn
-            self.stats.compiles += 1
-        else:
-            self.stats.cache_hits += 1
         return fn
 
     # -- device placement -------------------------------------------------
@@ -157,28 +152,30 @@ class BucketExecutor:
         # round-robin member→device chunks, zero-padding ragged tails
         chunks = round_robin_chunks(bucket.batch, self._ndev())
         order = [i for chunk in chunks for i in chunk]
-        mats = []
-        for i in order:
-            if i < 0:
-                mats.append(np.zeros(bucket.dims, np.float32))
-                continue
-            m = bucket.members[i]
-            x = np.asarray(
-                jax.device_get(leaves[m.index]), np.float32
-            ).reshape(m.dims)
-            if m.dims != bucket.dims:
-                x = np.pad(x, [(0, t - c) for c, t in zip(m.dims, bucket.dims)])
-            mats.append(x)
-        stacked = self._place(jnp.asarray(np.stack(mats)))
+        with obs.span("compress.gather"):
+            mats = []
+            reads = 0
+            for i in order:
+                if i < 0:
+                    mats.append(np.zeros(bucket.dims, np.float32))
+                    continue
+                m = bucket.members[i]
+                leaf = leaves[m.index]
+                reads += isinstance(leaf, jax.Array)
+                x = np.asarray(jax.device_get(leaf), np.float32
+                               ).reshape(m.dims)
+                if m.dims != bucket.dims:
+                    x = np.pad(x, [(0, t - c)
+                                   for c, t in zip(m.dims, bucket.dims)])
+                mats.append(x)
+            stacked = self._place(jnp.asarray(np.stack(mats)))
+            obs.count("compress.host_transfers", reads + 1)  # + the upload
 
-        fn = self._compiled(stacked, policy)
-        batched = fn(stacked)                       # ONE launch per bucket
+        with obs.span("compress.launch"):
+            fn = self._compiled(stacked, policy)
+            batched = fn(stacked)                   # ONE launch per bucket
         self.stats.bucket_launches += 1
         self.stats.batched_params += bucket.batch
-        self.stats.per_bucket.append({
-            "dims": bucket.dims, "batch": bucket.batch,
-            "launch_batch": len(order), "devices": self._ndev(),
-        })
 
         out = []
         for pos, i in enumerate(order):

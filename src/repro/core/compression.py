@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core import tt as _tt
 from repro.core import plan as _plan
 from repro.core import batch_exec as _exec
@@ -147,11 +148,12 @@ class TTCompressor:
     def compress(self, params, plan: Optional[str] = None
                  ) -> Tuple[Any, CompressionReport]:
         mode = plan or self.policy.plan
-        if mode == "serial":
-            return self._compress_serial(params)
-        if mode != "batched":
+        if mode not in ("serial", "batched"):
             raise ValueError(f"unknown compression plan: {mode!r}")
-        return self._compress_batched(params)
+        with obs.span("compress.pass"):
+            if mode == "serial":
+                return self._compress_serial(params)
+            return self._compress_batched(params)
 
     # ---- the original per-param loop: fallback + equivalence oracle ----
     def _compress_serial(self, params) -> Tuple[Any, CompressionReport]:

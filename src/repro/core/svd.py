@@ -32,6 +32,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.hbd import householder_bidiagonalize
 from repro.core import blocked as _blocked
 
@@ -85,8 +86,9 @@ def svd(
     orig = a.dtype
     u_b, bn, v_bt = _phase1(a.astype(jnp.float32), hbd_impl, panel)
     # Phase 2 on the compact n×n bidiagonal block.
-    q, s, pt = jnp.linalg.svd(bn, full_matrices=False)
-    res = _compose(u_b, q, s, pt, v_bt)
+    with jax.named_scope("hbd.phase2"):
+        q, s, pt = jnp.linalg.svd(bn, full_matrices=False)
+        res = _compose(u_b, q, s, pt, v_bt)
     return SVDResult(
         u=res.u.astype(orig), s=res.s.astype(orig), vt=res.vt.astype(orig)
     )
@@ -95,14 +97,15 @@ def svd(
 @functools.partial(jax.jit, static_argnames=("hbd_impl", "panel"))
 def _phase1(a32, hbd_impl, panel):
     """Tall f32 A → (thin U_B, B[:N, :N], V_B^T)."""
-    if hbd_impl == "blocked":
-        u_b, b, v_bt = _blocked.blocked_bidiagonalize(a32, panel=panel)
-    elif hbd_impl == "unblocked":
-        u_b, b, v_bt = householder_bidiagonalize(a32)
-    else:
-        raise ValueError(f"unknown hbd_impl: {hbd_impl}")
-    n = a32.shape[1]
-    return u_b, b[:n, :n], v_bt
+    with jax.named_scope("hbd.phase1"):
+        if hbd_impl == "blocked":
+            u_b, b, v_bt = _blocked.blocked_bidiagonalize(a32, panel=panel)
+        elif hbd_impl == "unblocked":
+            u_b, b, v_bt = householder_bidiagonalize(a32)
+        else:
+            raise ValueError(f"unknown hbd_impl: {hbd_impl}")
+        n = a32.shape[1]
+        return u_b, b[:n, :n], v_bt
 
 
 @jax.jit
@@ -132,6 +135,8 @@ def svd_host_diag(a: jax.Array, hbd_impl: str = "unblocked",
         return SVDResult(u=r.vt.T, s=r.s, vt=r.u.T)
     u_b, bn, v_bt = _phase1(jnp.asarray(a, jnp.float32), hbd_impl, panel)
     q, s, pt = np.linalg.svd(np.asarray(bn), full_matrices=False)
+    # B down, its three factors up (and A up when it came from the host)
+    obs.count("compress.host_transfers", 4 + (not isinstance(a, jax.Array)))
     res = _compose(u_b, jnp.asarray(q), jnp.asarray(s), jnp.asarray(pt),
                    v_bt)
     return SVDResult(

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.core.svd import svd as _svd_fn, svd_host_diag
 from repro.core import truncation as _trunc
 
@@ -76,6 +77,7 @@ def ttd(
          ||W - W_R||_F <= ε ||W||_F  (Oseledets 2011, the bound the paper's
          δ = ε/√(d-1)·||W||_F per-step budget enforces).
     """
+    obs.count("compress.host_transfers", isinstance(w, jax.Array))
     w = np.asarray(jax.device_get(w), dtype=np.float32)
     if dims is not None:
         assert int(np.prod(dims)) == w.size, (dims, w.shape)
@@ -83,6 +85,7 @@ def ttd(
     shape = w.shape
     d = w.ndim
     if d == 1:
+        obs.count("compress.host_transfers")
         core = jnp.asarray(w[None, :, None])
         return TTTensor(cores=[core], shape=shape, ranks=(1, 1), eps=eps)
 
@@ -103,6 +106,8 @@ def ttd(
         u = np.asarray(res.u)
         s = np.asarray(res.s)
         vt = np.asarray(res.vt)
+        # the unfolding up, its three factors down, the core up
+        obs.count("compress.host_transfers", 5)
         r = _trunc.truncation_rank(s, delta)                # δ-Trunc. (10)
         if max_rank is not None:
             r = min(r, max_rank)
@@ -111,6 +116,7 @@ def ttd(
         cores.append(jnp.asarray(u.reshape(ranks[-1], shape[k], r)))
         ranks.append(r)
     cores.append(jnp.asarray(w_temp.reshape(ranks[-1], shape[-1], 1)))
+    obs.count("compress.host_transfers")
     ranks.append(1)
     return TTTensor(cores=cores, shape=shape, ranks=tuple(ranks), eps=eps)
 
@@ -247,11 +253,16 @@ def static_tt_crop(tt: StaticTT, eps: float = 0.0) -> TTTensor:
     in-graph result into the compact host-side ``TTTensor`` the offline
     compressor trades in.
     """
-    ranks = [int(r) for r in np.asarray(jax.device_get(tt.ranks))]
-    cores = [
-        jnp.asarray(np.asarray(jax.device_get(c))[: ranks[k], :, : ranks[k + 1]])
-        for k, c in enumerate(tt.cores)
-    ]
+    with obs.span("compress.crop"):
+        obs.wait("compress.wait", tt.ranks)
+        ranks = [int(r) for r in np.asarray(jax.device_get(tt.ranks))]
+        cores = [
+            jnp.asarray(
+                np.asarray(jax.device_get(c))[: ranks[k], :, : ranks[k + 1]])
+            for k, c in enumerate(tt.cores)
+        ]
+        # the ranks down, each core down and back up
+        obs.count("compress.host_transfers", 1 + 2 * len(cores))
     return TTTensor(cores=cores, shape=tt.shape, ranks=tuple(ranks), eps=eps)
 
 

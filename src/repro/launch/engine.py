@@ -46,6 +46,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.models import common as model_common
 
 DRIVERS = ("fused", "python")
@@ -589,7 +590,9 @@ class Engine:
             if occ is not None and occ.uid == uid:
                 plen = len(occ.prompt)
                 avail = max(0, occ.gen - self._remaining[i])
-                return np.asarray(self.state.tokens[i, plen:plen + avail])
+                with obs.span("engine.peek"):
+                    obs.wait("engine.wait", self.state.tokens)
+                    return np.asarray(self.state.tokens[i, plen:plen + avail])
         return None
 
     # -- boundary admission (between fused chunks) --------------------------
@@ -599,9 +602,11 @@ class Engine:
         device read) and free it."""
         req = self._occupant[i]
         plen = len(req.prompt)
-        toks = np.asarray(self.state.tokens[i, plen:plen + req.gen])
-        plog = np.asarray(self.state.prompt_logits[i])
-        bad = bool(np.asarray(self.state.bad[i]))
+        with obs.span("engine.harvest"):
+            obs.wait("engine.wait", self.state.tokens)
+            toks = np.asarray(self.state.tokens[i, plen:plen + req.gen])
+            plog = np.asarray(self.state.prompt_logits[i])
+            bad = bool(np.asarray(self.state.bad[i]))
         self._occupant[i] = None
         return Completion(req.uid, toks, plog, bad=bad)
 
@@ -663,9 +668,13 @@ class Engine:
         self.state = _run_steps(self._step, self.params, self.state, n,
                                 self._chunk_sampling(self._occupant))
         self.steps += n
+        prompt_steps = 0
         for i in busy:
-            self.slot_steps += min(self._remaining[i], n)
+            rem = self._remaining[i]
+            self.slot_steps += min(rem, n)
+            prompt_steps += min(max(rem - self._occupant[i].gen, 0), n)
             self._remaining[i] -= n
+        obs.count("engine.prompt_slot_steps", prompt_steps)
         return done
 
     # -- scan admission (device-resident queue) -----------------------------
@@ -708,7 +717,7 @@ class Engine:
         rem = list(self._remaining)
         qi = 0
         retired: List[Request] = []
-        steps = busy_steps = 0
+        steps = busy_steps = prompt_steps = 0
         for _ in range(self.chunk_steps):
             if qi >= len(upload) and all(o is None for o in occ):
                 break                      # nothing left this chunk
@@ -722,6 +731,7 @@ class Engine:
             busy_steps += len(busy)
             steps += 1
             for i in busy:                 # device Phase B: one decode step
+                prompt_steps += rem[i] > occ[i].gen    # input is prompt
                 rem[i] -= 1
             for i in range(self.slots):    # device Phase C: retire + harvest
                 if occ[i] is not None and rem[i] <= 0:
@@ -738,13 +748,16 @@ class Engine:
         self._occupant, self._remaining = occ, rem
         self.steps += steps
         self.slot_steps += busy_steps
+        obs.count("engine.prompt_slot_steps", prompt_steps)
         out: List[Completion] = []
         if retired:
             # drain the done buffer — the once-per-request device read; row
             # order is the host-mirrored retirement order
-            dt = np.asarray(self.state.done.tokens[:len(retired)])
-            dl = np.asarray(self.state.done.prompt_logits[:len(retired)])
-            db = np.asarray(self.state.done.bad[:len(retired)])
+            with obs.span("engine.drain"):
+                obs.wait("engine.wait", self.state.tokens)
+                dt = np.asarray(self.state.done.tokens[:len(retired)])
+                dl = np.asarray(self.state.done.prompt_logits[:len(retired)])
+                db = np.asarray(self.state.done.bad[:len(retired)])
             for j, req in enumerate(retired):
                 plen = len(req.prompt)
                 out.append(Completion(
@@ -756,9 +769,14 @@ class Engine:
 
     def step_chunk(self) -> List[Completion]:
         """Advance the pool by one fused chunk; returns completions."""
-        if self.admission == "scan":
-            return self._step_chunk_scan()
-        return self._step_chunk_boundary()
+        with obs.span("engine.chunk"):
+            slot_steps = self.slot_steps
+            if self.admission == "scan":
+                done = self._step_chunk_scan()
+            else:
+                done = self._step_chunk_boundary()
+            obs.count("engine.slot_steps", self.slot_steps - slot_steps)
+            return done
 
     def run(self) -> List[Completion]:
         """Drain the queue; returns every completion (match by uid)."""

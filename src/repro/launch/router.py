@@ -61,6 +61,7 @@ from typing import Callable, List, Optional
 
 import numpy as np
 
+from repro import obs
 from repro.launch.engine import Completion, Engine
 from repro.runtime.fault_tolerance import RestartPolicy
 
@@ -666,14 +667,15 @@ class Router:
                             entry[2] = True
             # stream per-chunk deltas for still-in-flight tickets (one
             # device row read per streaming ticket per chunk)
-            for uid, ticket in live.items():
-                if not ticket.stream or uid in finished:
-                    continue
-                avail = eng.progress(uid)
-                if avail is not None and avail > sent[uid]:
-                    toks = eng.peek_tokens(uid)
-                    ticket._emit("delta", np.asarray(toks[sent[uid]:]))
-                    sent[uid] = avail
+            with obs.span("router.stream"):
+                for uid, ticket in live.items():
+                    if not ticket.stream or uid in finished:
+                        continue
+                    avail = eng.progress(uid)
+                    if avail is not None and avail > sent[uid]:
+                        toks = eng.peek_tokens(uid)
+                        ticket._emit("delta", np.asarray(toks[sent[uid]:]))
+                        sent[uid] = avail
             for c in done:
                 ticket = live.pop(c.uid, None)
                 n = sent.pop(c.uid, 0)

@@ -119,7 +119,8 @@ def dense_apply(x: jax.Array, w, in_ndim: int = 1) -> jax.Array:
     """
     from repro.core import tt_linear as _ttl
     if _ttl.is_tt_linear(w):
-        return _ttl.tt_apply(x, w)
+        with jax.named_scope("tt_chain"):
+            return _ttl.tt_apply(x, w)
     if w.dtype != x.dtype:
         dt = jnp.promote_types(x.dtype, w.dtype)
         x = x.astype(dt)
@@ -873,13 +874,15 @@ def unembed(x: jax.Array, embed: jax.Array, softcap: Optional[float] = None,
     # the embedding table is documented dense-resident (tied unembed; the
     # TT policy never compresses it), so this transposed lookup is the one
     # weight einsum with no dispatch to route through
-    # lint: skip[AST001]
-    logits = jnp.einsum(
-        "...d,vd->...v", x.astype(jnp.float32), embed.astype(jnp.float32)
-    )
-    if softcap is not None:
-        logits = jnp.tanh(logits / softcap) * softcap
-    if real_vocab is not None and real_vocab < embed.shape[0]:
-        pad_mask = jnp.arange(embed.shape[0]) >= real_vocab
-        logits = jnp.where(pad_mask, jnp.asarray(-1e30, logits.dtype), logits)
-    return logits
+    with jax.named_scope("unembed"):
+        # lint: skip[AST001]
+        logits = jnp.einsum(
+            "...d,vd->...v", x.astype(jnp.float32), embed.astype(jnp.float32)
+        )
+        if softcap is not None:
+            logits = jnp.tanh(logits / softcap) * softcap
+        if real_vocab is not None and real_vocab < embed.shape[0]:
+            pad_mask = jnp.arange(embed.shape[0]) >= real_vocab
+            logits = jnp.where(pad_mask, jnp.asarray(-1e30, logits.dtype),
+                               logits)
+        return logits

@@ -186,10 +186,11 @@ def decode_step(
     def step(h, lp, is_global, k_c, v_c):
         hh = common.rms_norm(h, lp.ln1, cfg.norm_eps)
         q, k_new, v_new = attn.qkv_project(hh, lp.attn, cfg, positions)
-        k_c, v_c = attn.cache_update(k_c, v_c, k_new, v_new, pos)
-        o = attn.decode_attend(
-            q, k_c, v_c, pos, cfg, window=cfg.window, is_global=is_global
-        )
+        with jax.named_scope("attention"):
+            k_c, v_c = attn.cache_update(k_c, v_c, k_new, v_new, pos)
+            o = attn.decode_attend(
+                q, k_c, v_c, pos, cfg, window=cfg.window, is_global=is_global
+            )
         h = h + common.dense_apply(o, lp.attn.wo, in_ndim=2)
         hh = common.rms_norm(h, lp.ln2, cfg.norm_eps)
         if cfg.moe is not None:

@@ -196,7 +196,7 @@ class FaultPlan:
         return hook
 
     def counts(self) -> Dict[str, int]:
-        """Planned injection counts (what BENCH_chaos.json records)."""
+        """Planned injection counts (what the chaos lane reports)."""
         return {
             "crashes": len(self.crash_at),
             "stalls": len(self.stall_at),
