@@ -311,3 +311,29 @@ def cache_update(k_cache, v_cache, k_new, v_new, pos):
         upd(k_cache, k_new.astype(k_cache.dtype), pos),
         upd(v_cache, v_new.astype(v_cache.dtype), pos),
     )
+
+
+def cache_write(k_all, v_all, k_new, v_new, layer, pos):
+    """Write layer ``layer``'s new K/V into the stacked (L, B, S_max, Hkv,
+    Dh) cache in place: one row per slot, at (layer, b, pos[b]).
+
+    The layer-stacked counterpart of ``cache_update`` for a cache carried
+    through the layer scan: nothing but the B new rows is read or written.
+    ``pos`` is () or (B,), clamped at the cache edge as ``cache_update``
+    clamps, so a frozen finished slot re-writes its last row.
+
+    One ``dynamic_update_slice`` per slot in a ``fori_loop``, so the
+    module holds one update per array whatever the slot count."""
+    b, s_max = k_all.shape[1], k_all.shape[2]
+    row = jnp.broadcast_to(jnp.clip(pos, 0, s_max - 1), (b,))
+    zero = jnp.zeros((), row.dtype)
+    layer = jnp.asarray(layer, row.dtype)
+    k_new = k_new.astype(k_all.dtype)
+    v_new = v_new.astype(v_all.dtype)
+
+    def put(s, kv):                      # k_new, v_new: (B, 1, Hkv, Dh)
+        idx = (layer, s, row[s], zero, zero)
+        return (jax.lax.dynamic_update_slice(kv[0], k_new[s][None, None], idx),
+                jax.lax.dynamic_update_slice(kv[1], v_new[s][None, None], idx))
+
+    return jax.lax.fori_loop(0, b, put, (k_all, v_all))

@@ -183,13 +183,16 @@ def decode_step(
     positions = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)), (b, 1))
     flags = _layer_flags(cfg)
 
-    def step(h, lp, is_global, k_c, v_c):
+    def step(carry, lp, is_global, i):
+        h, k_all, v_all = carry
         hh = common.rms_norm(h, lp.ln1, cfg.norm_eps)
         q, k_new, v_new = attn.qkv_project(hh, lp.attn, cfg, positions)
         with jax.named_scope("attention"):
-            k_c, v_c = attn.cache_update(k_c, v_c, k_new, v_new, pos)
+            k_all, v_all = attn.cache_write(k_all, v_all, k_new, v_new, i,
+                                            pos)
             o = attn.decode_attend(
-                q, k_c, v_c, pos, cfg, window=cfg.window, is_global=is_global
+                q, k_all[i], v_all[i], pos, cfg, window=cfg.window,
+                is_global=is_global,
             )
         h = h + common.dense_apply(o, lp.attn.wo, in_ndim=2)
         hh = common.rms_norm(h, lp.ln2, cfg.norm_eps)
@@ -197,13 +200,16 @@ def decode_step(
             f = mlp_mod.moe_apply(hh, lp.moe, cfg)
         else:
             f = mlp_mod.mlp_apply(hh, lp.mlp, cfg.act)
-        return (h + f).astype(h.dtype), (k_c, v_c)
+        return ((h + f).astype(h.dtype), k_all, v_all), None
 
-    # TT-native decode: weights never leave TT form — common.tt_scan
-    # carries only the layer index; cores are closure constants
-    x, (k_all, v_all) = common.tt_scan(
-        step, x, params.layers, xs=(flags, cache.k, cache.v),
-        length=cfg.num_layers,
+    # The whole cache rides the layer scan's carry so that each layer
+    # writes its B new rows in place; scan xs/ys would copy every layer's
+    # slice out and stack it back each step.  TT-native decode: weights
+    # never leave TT form — common.tt_scan carries only the layer index;
+    # cores are closure constants.
+    (x, k_all, v_all), _ = common.tt_scan(
+        step, (x, cache.k, cache.v), params.layers,
+        xs=(flags, jnp.arange(cfg.num_layers)), length=cfg.num_layers,
     )
     hidden = common.rms_norm(x, params.final_norm, cfg.norm_eps)
     logits = logits_fn(params, hidden, cfg)

@@ -9,7 +9,9 @@ test of this kind lives in this one file, pinned to one xdist worker
 library.  Where it cannot be described, the tests skip.
 """
 
+import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -109,3 +111,97 @@ def test_hbd_unfolding_fits_one_chip(one_chip):
     total = (mem.argument_size_in_bytes + mem.output_size_in_bytes
              + mem.temp_size_in_bytes)
     assert total < V5E_HBM, total
+
+
+# the decode cell's pool: 32 slots of 576 rows, 16 KV heads of 64, at
+# qwen1.5-0.5b's widths with two layers
+SLOTS, MAX_LEN, CHUNK = 32, 576, 8
+
+
+def _hlo_computations(text):
+    """{computation name: its instruction lines} of a compiled module."""
+    comps, cur = {}, None
+    for line in text.splitlines():
+        m = re.match(r"^(ENTRY )?%?([\w.\-]+) .*\{\s*$", line)
+        if m:
+            cur = comps.setdefault(("ENTRY " if m.group(1) else "")
+                                   + m.group(2), [])
+        elif cur is not None and line.startswith(" "):
+            cur.append(line.strip())
+    return comps
+
+
+def _instruction(line):
+    """(name, result shape, opcode, called computation) of one line."""
+    m = re.match(r"(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+    if m is None:
+        return None
+    calls = re.search(r"calls=%?([\w.\-]+)", line)
+    return m.group(1), m.group(2), m.group(3), calls and calls.group(1)
+
+
+def test_decode_chunk_updates_the_cache_in_place(one_chip):
+    """The engine's fused chunk (``_run_steps`` over the transformer decode
+    step), compiled for the chip at the decode cell's pool: inside its
+    loops nothing copies, slices out or stacks the cache or a layer of it.
+    The only cache-sized instructions there are fusions rooted in a
+    dynamic-update-slice, which write in place: a write the compiler could
+    not place in the carried buffer would come with a copy."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.kernels import common as kcommon
+    from repro.launch import engine as engine_mod
+    from repro.launch.engine import Engine
+    from repro.models import common as mc
+    from repro.models.registry import build
+
+    cfg = dataclasses.replace(get_config("qwen1.5-0.5b"), num_layers=2)
+    model = build(cfg)
+    params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
+    state = jax.eval_shape(lambda: Engine(
+        model, params, slots=SLOTS, max_len=MAX_LEN,
+        chunk_steps=CHUNK).state)
+    put = functools.partial(jax.tree.map, lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip))
+    interpret = kcommon.use_interpret
+    kcommon.use_interpret = lambda: False
+    try:
+        text = engine_mod._run_steps.lower(
+            model.decode_step, put(params), put(state), CHUNK,
+            mc.GREEDY).compile().as_text()
+    finally:
+        kcommon.use_interpret = interpret
+
+    layer = f"[{SLOTS},{MAX_LEN},{cfg.num_kv_heads},{cfg.resolved_head_dim}]"
+    whole = "[" + f"{cfg.num_layers}," + layer[1:]
+    comps = _hlo_computations(text)
+    fused = {}
+    for lines in comps.values():
+        for line in lines:
+            ins = _instruction(line)
+            if ins and ins[2] == "fusion":
+                fused[ins[3]] = None
+    for name in fused:
+        root = [l for l in comps[name] if l.startswith("ROOT ")]
+        fused[name] = _instruction(root[0])[2]
+    n_writes = 0
+    for name, lines in comps.items():
+        if name.startswith("ENTRY") or name in fused:
+            continue
+        for line in lines:
+            ins = _instruction(line)
+            if ins is None or ins[1].startswith("("):
+                continue
+            _, shape, op, calls = ins
+            if op in ("parameter", "get-tuple-element", "bitcast"):
+                continue
+            if shape.startswith(("bf16" + layer, "f32" + layer)):
+                raise AssertionError(f"a layer of the cache made in {name}: "
+                                     f"{line[:160]}")
+            if shape.startswith("bf16" + whole):
+                assert op == "fusion" \
+                    and fused[calls] == "dynamic-update-slice", \
+                    f"the cache copied in {name}: {line[:160]}"
+                n_writes += 1
+    assert n_writes, "no in-place write of the cache found"
